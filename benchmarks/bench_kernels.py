@@ -1,8 +1,8 @@
 """Kernel-layer benchmark: the paper's two hot spots as MXU contractions.
 
-On CPU we time the jnp oracle (the XLA-native path actually executing) and
-run the Pallas kernels in interpret mode for correctness; on a real TPU the
-same harness times the kernels themselves (interpret=False is automatic).
+We time the jnp oracle and check the Pallas kernels against it.  Off the
+TPU the kernels run in the Pallas interpreter, a correctness check only;
+no kernel time is taken here.
 
 CSV: name,us_per_call,derived
 """
@@ -33,13 +33,15 @@ def run(n_clients: int = 2048, dim: int = 4096, k: int = 16,
         bins: int = 16, feat_d: int = 512, seed: int = 0) -> list:
     rs = np.random.RandomState(seed)
     rows = []
+    interp = not ops.on_tpu()
 
     # K-means assignment distances (clients x centroids)
     x = jnp.asarray(rs.normal(size=(n_clients, dim)), jnp.float32)
     c = jnp.asarray(rs.normal(size=(k, dim)), jnp.float32)
     jit_ref = jax.jit(ref.pairwise_dist_ref)
     t = _time(jit_ref, x, c)
-    err = float(jnp.max(jnp.abs(ops.pairwise_dist(x, c) - jit_ref(x, c))))
+    err = float(jnp.max(jnp.abs(ops.pairwise_dist(x, c, interpret=interp)
+                                - jit_ref(x, c))))
     rows.append({"name": "kernels/pairwise_dist", "us": t * 1e6,
                  "derived": f"gflops={2 * n_clients * k * dim / t / 1e9:.1f};"
                             f"kernel_vs_ref_err={err:.1e}"})
@@ -50,7 +52,8 @@ def run(n_clients: int = 2048, dim: int = 4096, k: int = 16,
     keep = jnp.ones(coreset, bool)
     jit_sm = jax.jit(ref.seg_mean_ref, static_argnums=3)
     t = _time(jit_sm, f, lab, keep, classes)
-    err = float(jnp.max(jnp.abs(ops.seg_mean(f, lab, keep, classes)
+    err = float(jnp.max(jnp.abs(ops.seg_mean(f, lab, keep, classes,
+                                             interpret=interp)
                                 - jit_sm(f, lab, keep, classes))))
     rows.append({"name": "kernels/seg_mean", "us": t * 1e6,
                  "derived": f"kernel_vs_ref_err={err:.1e}"})
@@ -60,7 +63,8 @@ def run(n_clients: int = 2048, dim: int = 4096, k: int = 16,
     v = jnp.ones(coreset, bool)
     jit_ch = jax.jit(ref.class_hist_ref, static_argnums=(3, 4))
     t = _time(jit_ch, q, lab, v, classes, bins)
-    err = float(jnp.max(jnp.abs(ops.class_hist(q, lab, v, classes, bins)
+    err = float(jnp.max(jnp.abs(ops.class_hist(q, lab, v, classes, bins,
+                                               interpret=interp)
                                 - jit_ch(q, lab, v, classes, bins))))
     rows.append({"name": "kernels/class_hist", "us": t * 1e6,
                  "derived": f"kernel_vs_ref_err={err:.1e}"})

@@ -64,6 +64,7 @@ from benchmarks import (
     bench_summary_pipeline,
 )
 from benchmarks._record import SCHEMA_VERSION
+from repro.utils.cache import use_compile_cache
 
 BENCHES = (
     ("summary", bench_summary.main),
@@ -207,4 +208,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

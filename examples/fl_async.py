@@ -18,6 +18,7 @@ import numpy as np
 import repro.api as api
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.sim import DATA_HINTS, PRESET_NAMES, Scenario, make_scenario
+from repro.utils.cache import use_compile_cache
 
 
 def run_one(server: str, data, sc_config: dict, args) -> dict:
@@ -113,4 +114,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -40,6 +40,7 @@ from repro.data.synthetic import FederatedDataset, small_spec
 from repro.obs.explain import Flight, format_why, why
 from repro.obs.export import validate_chrome_trace
 from repro.sim import presets
+from repro.utils.cache import use_compile_cache
 
 
 def main():
@@ -126,4 +127,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

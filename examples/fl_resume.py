@@ -34,6 +34,7 @@ from repro.sim import (
     FaultPlan, PRESET_NAMES, Scenario, ServerKilled, make_scenario,
     resume_trace,
 )
+from repro.utils.cache import use_compile_cache
 
 
 def main():
@@ -114,4 +115,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -19,6 +19,7 @@ import numpy as np
 import repro.api as api
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.sim import DATA_HINTS, PRESET_NAMES, make_scenario
+from repro.utils.cache import use_compile_cache
 
 
 def run_preset(preset: str, args) -> dict:
@@ -83,4 +84,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -16,6 +16,7 @@ import numpy as np
 import repro.api as api
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl.system import SystemSpec
+from repro.utils.cache import use_compile_cache
 
 
 def main():
@@ -73,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -20,6 +20,9 @@ from repro.core import (SelectionConfig, encoder_summary, kmeans,
                         label_distribution, pxy_histogram, select_devices)
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.models.cnn import CNNConfig, build_cnn, cnn_apply
+from repro.utils.cache import use_compile_cache
+
+use_compile_cache()
 
 # alpha=50 -> near-IID labels: only FEATURE heterogeneity separates clients,
 # the regime where the paper shows P(y) fails and the encoder summary wins

@@ -10,6 +10,8 @@ a reduced model, demonstrating rolling-window caches (gemma3/llama4),
 SSM state caches (hymba/xlstm) and MLA latent caches (deepseek-v3).
 """
 from repro.launch.serve import main
+from repro.utils.cache import use_compile_cache
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -15,6 +15,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks import bench_clustering, bench_summary  # noqa: E402
+from repro.utils.cache import use_compile_cache  # noqa: E402
 
 
 def main():
@@ -28,4 +29,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

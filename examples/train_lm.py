@@ -10,7 +10,9 @@ for a fast sanity run; checkpointing via --checkpoint ckpt/run1.
 import sys
 
 from repro.launch.train import main
+from repro.utils.cache import use_compile_cache
 
 if __name__ == "__main__":
+    use_compile_cache()
     main(sys.argv[1:] or ["--arch", "phi4-mini-3.8b", "--preset", "100m",
                           "--steps", "300", "--batch", "4", "--seq", "256"])

@@ -66,12 +66,14 @@ def batched_label_distribution(labels, valid, num_classes: int):
 
 
 def batched_pxy_histogram(feats, labels, valid, num_classes: int,
-                          bins: int = 16, use_kernel: bool = False):
+                          bins: int = 16, use_kernel: bool = False,
+                          interpret: bool = False):
     """[M, N, D] features -> [M, C*D*B] per-client P(X|y) histograms.
 
     With ``use_kernel`` the M histograms collapse into one ``class_hist``
     launch over ``M*C`` offset classes (label-offset trick, DESIGN.md §4);
-    otherwise the single-client one-hot einsum is vmapped.
+    otherwise the single-client one-hot einsum is vmapped.  ``interpret``
+    runs that kernel in the Pallas interpreter (CPU tests).
     """
     if use_kernel:
         from repro.kernels.ops import class_hist
@@ -79,7 +81,8 @@ def batched_pxy_histogram(feats, labels, valid, num_classes: int,
         q = quantize(feats, bins).reshape(m * n, d)
         offset = labels + num_classes * jnp.arange(m, dtype=labels.dtype)[:, None]
         hist = class_hist(q, offset.reshape(-1), valid.reshape(-1),
-                          m * num_classes, bins)          # [M*C, D, B]
+                          m * num_classes, bins,
+                          interpret=interpret)            # [M*C, D, B]
         hist = hist.reshape(m, num_classes, d, bins)
         denom = jnp.maximum(jnp.sum(hist, axis=-1, keepdims=True), 1.0)
         return (hist / denom).reshape(m, -1)
@@ -89,7 +92,7 @@ def batched_pxy_histogram(feats, labels, valid, num_classes: int,
 
 
 def batched_per_label_mean(feats, labels, keep, num_classes: int,
-                           use_kernel: bool = False):
+                           use_kernel: bool = False, interpret: bool = False):
     """[M, k, H] features -> [M, C, H] per-client per-label means.
 
     Kernel path: one ``seg_mean`` launch over ``M*C`` offset classes.
@@ -99,7 +102,8 @@ def batched_per_label_mean(feats, labels, keep, num_classes: int,
         m, k, h = feats.shape
         offset = labels + num_classes * jnp.arange(m, dtype=labels.dtype)[:, None]
         out = seg_mean(feats.reshape(m * k, h), offset.reshape(-1),
-                       keep.reshape(-1), m * num_classes)  # [M*C, H]
+                       keep.reshape(-1), m * num_classes,
+                       interpret=interpret)                # [M*C, H]
         return out.reshape(m, num_classes, h)
     return jax.vmap(lambda f, l, kp: per_label_mean(f, l, kp, num_classes))(
         feats, labels, keep)
@@ -134,6 +138,27 @@ def batched_encoder_summary(feats, labels, valid, encoder_fn: Callable,
 # the engine: bucketing, padding, dispatch accounting
 
 
+def _offset_batch_bound(method: str, num_classes: int, bins: int,
+                        encoder_dim: int | None) -> int:
+    """Largest power-of-two client count whose ``M·C`` offset classes fit
+    one label-offset kernel launch (DESIGN.md §4)."""
+    from repro.kernels.ops import class_hist_max_classes, seg_mean_max_classes
+    if method == "pxy":
+        limit = class_hist_max_classes(bins)
+    else:
+        if encoder_dim is None:
+            raise ValueError("the encoder kernel path needs encoder_dim")
+        limit = seg_mean_max_classes(encoder_dim)
+    if num_classes > limit:
+        raise ValueError(
+            f"{num_classes} classes exceed the {limit} one {method} kernel "
+            "launch holds")
+    m = 1
+    while 2 * m * num_classes <= limit:
+        m *= 2
+    return m
+
+
 class SummaryResult(NamedTuple):
     summary: np.ndarray      # flat summary vector
     label_dist: np.ndarray   # empirical P(y) over the (padded) client data
@@ -155,18 +180,25 @@ class BatchedSummaryEngine:
     Parameters mirror ``fl.client.timed_summary``; ``max_batch`` bounds the
     number of clients stacked into one dispatch (memory ceiling — the
     transient one-hots of the ``pxy`` family scale with M·N·D·B, so its
-    default is far smaller than the other families').
+    default is far smaller than the other families').  With ``use_kernel``
+    the label-offset accumulator of ``M·C`` classes must also fit in VMEM,
+    so ``max_batch`` is cut to the largest power of two (the padded client
+    axis) whose ``M·C`` the kernel holds.
     """
 
     def __init__(self, method: str, num_classes: int, *, encoder_fn=None,
                  coreset_k: int = 128, bins: int = 16,
-                 use_kernel: bool = False, max_batch: int | None = None):
+                 use_kernel: bool = False, max_batch: int | None = None,
+                 encoder_dim: int | None = None):
         if method not in ("py", "pxy", "encoder"):
             raise ValueError(f"unknown summary method: {method}")
         if method == "encoder" and encoder_fn is None:
             raise ValueError("encoder summary requires encoder_fn")
         if max_batch is None:
             max_batch = 16 if method == "pxy" else 256
+        if use_kernel and method != "py":
+            max_batch = min(max_batch, _offset_batch_bound(
+                method, num_classes, bins, encoder_dim))
         self.method = method
         self.num_classes = num_classes
         self.encoder_fn = encoder_fn

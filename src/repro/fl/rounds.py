@@ -66,6 +66,7 @@ from repro.fl.aggregation import fedavg
 from repro.fl.client import ClientRuntime, local_train, timed_summary
 from repro.fl.models import make_classifier, xent_loss
 from repro.fl.system import SystemModel, SystemSpec, completion_times
+from repro.kernels.ops import on_tpu
 from repro.utils.tree import global_norm
 from repro.models.cnn import CNNConfig, build_cnn, cnn_apply
 from repro.optim import sgd
@@ -258,6 +259,8 @@ class RoundContext:
                             feature_dim=cfg.encoder_dim)
         enc_params = build_cnn(enc_cfg, jax.random.PRNGKey(7))
         self.enc_fn = jax.jit(lambda imgs: cnn_apply(enc_params, imgs))
+        # the Pallas kernels on the chip, the jnp references elsewhere
+        self.use_kernel = on_tpu()
 
         if cfg.summary_engine not in ("batched", "perclient"):
             raise ValueError(f"unknown summary_engine: {cfg.summary_engine}")
@@ -265,7 +268,8 @@ class RoundContext:
         if cfg.summary != "none" and cfg.summary_engine == "batched":
             self.engine = BatchedSummaryEngine(
                 cfg.summary, spec.num_classes, encoder_fn=self.enc_fn,
-                coreset_k=cfg.coreset_k, bins=cfg.bins)
+                coreset_k=cfg.coreset_k, bins=cfg.bins,
+                use_kernel=self.use_kernel, encoder_dim=cfg.encoder_dim)
         policy = RefreshPolicy(cfg.refresh_max_age, cfg.refresh_kl)
         if cfg.registry == "streaming":
             self.registry = StreamingSummaryRegistry(
@@ -290,7 +294,8 @@ class RoundContext:
             raise ValueError(f"unknown frontend: {cfg.frontend}")
         self.maintainer = None
         online_policy = OnlinePolicy(inertia_ratio=cfg.online_inertia_ratio,
-                                     reseed_every=cfg.online_reseed_every)
+                                     reseed_every=cfg.online_reseed_every,
+                                     use_kernel=self.use_kernel)
         if cfg.clustering == "online":
             self.maintainer = OnlineClusterMaintainer(cfg.num_clusters,
                                                       online_policy)
@@ -426,7 +431,7 @@ class RoundContext:
                 s, _ld_emp, dt = timed_summary(
                     cfg.summary, feats, labels, valid, self.spec.num_classes,
                     encoder_fn=self.enc_fn, coreset_k=cfg.coreset_k,
-                    bins=cfg.bins,
+                    bins=cfg.bins, use_kernel=self.use_kernel,
                     key=jax.random.PRNGKey(rnd * 100003 + c))
                 summaries[c] = s
                 times[c] = dt
@@ -515,7 +520,8 @@ class RoundContext:
                     cluster_fn = (minibatch_kmeans
                                   if cfg.clustering == "minibatch" else kmeans)
                     res = cluster_fn(X, cfg.num_clusters,
-                                     jax.random.PRNGKey(cfg.seed + rnd))
+                                     jax.random.PRNGKey(cfg.seed + rnd),
+                                     use_kernel=self.use_kernel)
                     assignment[have_ids] = np.asarray(res.assignment, np.int64)
                     self.num_clusters = cfg.num_clusters
                 else:

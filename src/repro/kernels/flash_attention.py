@@ -10,10 +10,11 @@ memory profile, tiled for the MXU (128-aligned Bq×Bk×D blocks).
 
 Supports causal masking, sliding windows and GQA (q heads grouped onto KV
 heads via the BlockSpec index map).  Validated against the pure-jnp oracle
-(`repro.models.attention.attend`) in interpret mode on CPU; `ops.py` routes
-to it on TPU.  Training uses the custom_vjp streaming implementation in
-models/attention.py (same math, autodiff-ready); this kernel is the serving
-/ prefill fast path and the deployment artifact for the memory-term fix.
+(`repro.models.attention.attend`) in interpret mode on CPU, which its
+tests ask for (``interpret=True``).  Training uses the custom_vjp streaming
+implementation in models/attention.py (same math, autodiff-ready); this
+kernel is the serving / prefill fast path and the deployment artifact for
+the memory-term fix.
 """
 from __future__ import annotations
 
@@ -76,7 +77,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "interpret"))
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q [B,H,Sq,D], k/v [B,KV,Sk,D] -> o [B,H,Sq,D].  Sq%bq == Sk%bk == 0."""
     B, H, Sq, D = q.shape
     KV, Sk = k.shape[1], k.shape[2]

@@ -39,7 +39,7 @@ def _kernel(x_ref, c_ref, o_ref, *, nd: int):
 @functools.partial(jax.jit,
                    static_argnames=("bn", "bk", "bd", "interpret"))
 def pairwise_dist_kernel(x, c, *, bn: int = 128, bk: int = 128, bd: int = 512,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """x [N,D], c [K,D] -> [N,K] fp32.  Caller pads to block multiples."""
     n, d = x.shape
     k = c.shape[0]

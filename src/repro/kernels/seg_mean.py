@@ -42,7 +42,7 @@ def _kernel(feats_ref, labels_ref, keep_ref, sums_ref, counts_ref,
 
 @functools.partial(jax.jit, static_argnames=("num_classes", "bn", "interpret"))
 def seg_mean_kernel(feats, labels, keep, num_classes: int, *, bn: int = 256,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """feats [N,H], labels [N] int32, keep [N] bool -> [C,H] fp32 means."""
     n, h = feats.shape
     assert n % bn == 0, (n, bn)

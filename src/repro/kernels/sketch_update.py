@@ -75,7 +75,7 @@ def _kernel(labels_ref, seg_ref, valid_ref, o_ref, *, num_slots: int,
                                     "interpret"))
 def sketch_update_kernel(labels, seg, valid, num_slots: int, width: int,
                          a: tuple, b: tuple, *, bn: int = 256,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """labels [N] int32, seg [N] int32 slot ids, valid [N] bool ->
     [M, R, W] fp32 count-min increments (add to an existing sketch to
     update; sketches merge by addition)."""

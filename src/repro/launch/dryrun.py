@@ -1,10 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the production meshes, and extract the roofline terms from the compiled
-artifact.  MUST keep the two lines above as the very first statements —
-jax locks the device count on first initialization.
+artifact.  MUST keep the three lines above as the very first statements —
+jax locks the device count on first initialization.  The tool runs on 512
+forced host devices only: ``JAX_PLATFORMS=cpu`` keeps it, and the children
+``--all`` starts (which inherit the environment), off any attached chip.
 
 Usage:
     python -m repro.launch.dryrun --arch gemma3-1b --shape train_4k

@@ -36,13 +36,14 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.obs as obs
 from repro.core.scheduler import RefreshPolicy, batch_sym_kl
 from repro.stream.registry import StreamingSummaryRegistry
-from repro.utils.roofline import drift_scan_bytes, record_bandwidth
+from repro.utils.roofline import (
+    device_peaks, drift_scan_bytes, record_bandwidth,
+)
 from repro.utils.sharding import FLEET_RULES, fleet_mesh, make_spec
 
 
@@ -67,8 +68,8 @@ def _drift_scan(mesh: Mesh, rows: int, num_classes: int):
     compile (the differential tests build many registries)."""
     spec = make_spec(("clients", None), (rows, num_classes), mesh,
                      rules=FLEET_RULES)
-    sharded = shard_map(_sym_kl_rows, mesh=mesh,
-                        in_specs=(spec, spec), out_specs=P(*spec[:1]))
+    sharded = jax.shard_map(_sym_kl_rows, mesh=mesh,
+                            in_specs=(spec, spec), out_specs=P(*spec[:1]))
     return jax.jit(sharded,
                    in_shardings=NamedSharding(mesh, spec),
                    out_shardings=NamedSharding(mesh, P(*spec[:1])))
@@ -140,10 +141,14 @@ class ShardedSummaryRegistry(StreamingSummaryRegistry):
                 self.scan_chunks += 1
             sp.annotate(chunks=-(-n // rows))
         if observed:
-            # achieved vs roofline-predicted scan bandwidth (gauges)
+            # achieved vs roofline-predicted scan bandwidth (gauges),
+            # against the HBM of every device in the mesh
+            peaks = device_peaks(self.mesh.devices.flat[0].device_kind)
             record_bandwidth(obs.metrics(), "kernel/drift_scan",
                              drift_scan_bytes(n, c),
-                             time.perf_counter() - t_scan)
+                             time.perf_counter() - t_scan,
+                             peak_bw=(peaks.hbm_bw * self.n_shards
+                                      if peaks else None))
         # borderline band: device libm may differ from numpy by ~1 ulp, so
         # rows near the threshold are re-decided with the exact baseline
         # math — decisions match the streaming registry on any mesh

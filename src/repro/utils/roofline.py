@@ -1,24 +1,45 @@
 """Roofline math for the TPU v5e target.
 
-The container is CPU-only; the dry-run gives us compiled HLO FLOPs / bytes /
-collective traffic, and this module turns those into the three roofline
-terms per chip:
+The dry-run gives us compiled HLO FLOPs / bytes / collective traffic, and
+this module turns those into the three roofline terms per chip:
 
     compute    = HLO_FLOPs      / (chips * PEAK_FLOPS)
     memory     = HLO_bytes      / (chips * HBM_BW)
     collective = collective_B   / (chips * ICI_BW)
 
-Hardware constants are fixed by the task: TPU v5e — 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks live in ``PEAKS``, keyed by ``jax.Device.device_kind``.  A device
+that is not in the table has no peak: nothing divides by one for it.
 """
 from __future__ import annotations
 
 import dataclasses
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
-HBM_PER_CHIP = 16 * 1024**3  # v5e: 16 GiB
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # bytes/s per chip
+    ici_bw: float       # bytes/s per link
+    hbm_bytes: int      # HBM per chip
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over 4 links.
+V5E = DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                  hbm_bytes=16 * 1024**3)
+PEAKS: dict[str, DevicePeaks] = {"TPU v5 lite": V5E, "TPU v5e": V5E}
+
+# the dry-run's target (production v5e meshes)
+PEAK_FLOPS = V5E.flops
+HBM_BW = V5E.hbm_bw
+ICI_BW = V5E.ici_bw
+HBM_PER_CHIP = V5E.hbm_bytes
+
+
+def device_peaks(device_kind: str) -> DevicePeaks | None:
+    """Published peaks of ``device_kind``; None for a device not in the
+    table (the CPU among them)."""
+    return PEAKS.get(device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,17 +121,18 @@ def drift_scan_bytes(rows: int, num_classes: int,
 
 
 def record_bandwidth(metrics, name: str, nbytes: float, seconds: float,
-                     peak_bw: float = HBM_BW) -> float:
+                     peak_bw: float | None = HBM_BW) -> float:
     """Record achieved vs roofline-predicted bandwidth for one measured
     pass as gauges (``<name>/achieved_gbs``, ``<name>/predicted_gbs``,
     ``<name>/efficiency``) on a metric registry; returns the achieved
-    bytes/s.  On the CPU-only container "efficiency" is a cross-check
-    number, not a target — the predicted term assumes the v5e HBM figure.
+    bytes/s.  ``peak_bw=None`` (a device with no published peak) records
+    the achieved figure only.
     """
     achieved = nbytes / seconds if seconds > 0 else float("nan")
     metrics.gauge(f"{name}/achieved_gbs").set(achieved / 1e9)
-    metrics.gauge(f"{name}/predicted_gbs").set(peak_bw / 1e9)
-    metrics.gauge(f"{name}/efficiency").set(achieved / peak_bw)
+    if peak_bw is not None:
+        metrics.gauge(f"{name}/predicted_gbs").set(peak_bw / 1e9)
+        metrics.gauge(f"{name}/efficiency").set(achieved / peak_bw)
     return achieved
 
 

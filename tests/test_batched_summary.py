@@ -117,7 +117,8 @@ def test_label_offset_kernel_paths_match(rs, fn, extra):
         else rs.randn(m, n, d)
     x = jnp.asarray(x, jnp.float32)
     ref = fn(x, labels, valid, C, use_kernel=False, **extra)
-    ker = fn(x, labels, valid, C, use_kernel=True, **extra)
+    ker = fn(x, labels, valid, C, use_kernel=True, interpret=True,
+             **extra)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
 
 

@@ -20,7 +20,9 @@ from repro.obs.export import (
     metrics_records, read_metrics_jsonl, validate_chrome_trace,
     write_metrics_jsonl, write_trace,
 )
-from repro.utils.roofline import HBM_BW, drift_scan_bytes, record_bandwidth
+from repro.utils.roofline import (
+    HBM_BW, device_peaks, drift_scan_bytes, record_bandwidth,
+)
 
 # the deterministic keys of the 24-seed differential pin — telemetry
 # must never move them, enabled or not.  (``sim_time`` is pinned there
@@ -284,6 +286,39 @@ def test_record_bandwidth_gauges():
         pytest.approx(HBM_BW / 1e9)
     assert r.gauge("kernel/drift_scan/efficiency").value == \
         pytest.approx(achieved / HBM_BW)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e.flops == 197e12 and v5e.hbm_bw == HBM_BW == 819e9
+    assert device_peaks("cpu") is None
+
+
+def test_record_bandwidth_no_efficiency_for_unknown_device():
+    r = MetricRegistry()
+    record_bandwidth(r, "kernel/drift_scan", 1e6, 1e-3,
+                     peak_bw=device_peaks("cpu"))
+    assert r.gauge("kernel/drift_scan/achieved_gbs").value == \
+        pytest.approx(1.0)
+    assert r.get("kernel/drift_scan/efficiency") is None
+    assert r.get("kernel/drift_scan/predicted_gbs") is None
+
+
+def test_drift_scan_on_cpu_writes_no_efficiency_gauge():
+    """The sharded registry looks its device up in the peaks table; the
+    CPU has no entry, so the scan records achieved bandwidth only."""
+    from repro.core.scheduler import RefreshPolicy
+    from repro.shard import ShardedSummaryRegistry
+    n, c = 64, 5
+    rs = np.random.RandomState(0)
+    ld = rs.dirichlet([0.5] * c, n).astype(np.float32)
+    reg = ShardedSummaryRegistry(n, RefreshPolicy(10 ** 6, 0.05),
+                                 num_classes=c, chunk_rows=32)
+    reg.update_batch(np.arange(n), 0, ld, ld)
+    with obs.observe() as ob:
+        reg.stale_mask(1, ld)
+    assert ob.metrics.get("kernel/drift_scan/achieved_gbs") is not None
+    assert ob.metrics.get("kernel/drift_scan/efficiency") is None
 
 
 # ---------------------------------------------------------------------------

@@ -40,7 +40,8 @@ def test_sketch_update_kernel_matches_ref(rs):
         seg = jnp.asarray(rs.randint(0, m, n), jnp.int32)
         valid = jnp.asarray(rs.rand(n) > 0.2)
         a, b = cm_hash_params(r, seed=1)
-        got = ops.sketch_update(labels, seg, valid, m, w, a, b)
+        got = ops.sketch_update(labels, seg, valid, m, w, a, b,
+                                 interpret=True)
         want = ref.sketch_update_ref(labels, seg, valid, m, w, a, b)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0)
         # counts conservation: every valid item lands once per row
