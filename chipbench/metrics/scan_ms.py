@@ -1,0 +1,9 @@
+"""scan_ms: mean milliseconds per window round of the harness's host span
+around the scan stage (see chipbench/server.py)."""
+
+
+def read(obs):
+    if not obs["rounds"]:
+        return None
+    i = obs["stages"].index("scan")
+    return float(obs["spans"][:, i].mean() * 1e3)
