@@ -126,10 +126,6 @@ def fleet_phase(data, cfg) -> dict:
               f"check-ins {h['checkins'][r]}, "
               f"snapshot v{h['snapshot_version'][r]}, acc {h['acc'][r]:.4f}",
               flush=True)
-    eff = ob.metrics.get("kernel/drift_scan/efficiency")
-    print(f"  drift-scan roofline gauge: "
-          f"{'written' if eff is not None else 'not written'} "
-          f"(written only for a device in utils.roofline.PEAKS)")
     print(f"  online clustering: {h['online_cluster']}")
     print(f"final accuracy {h['final_acc']:.4f}")
     if h["refreshes"][0] != n:

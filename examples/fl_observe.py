@@ -52,9 +52,6 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="obs_artifacts",
                     help="artifact directory (trace.json, metrics.jsonl)")
-    ap.add_argument("--kernel-profile", action="store_true",
-                    help="also annotate XLA device traces "
-                         "(jax.profiler.TraceAnnotation)")
     args = ap.parse_args()
 
     data = FederatedDataset(small_spec(num_clients=args.clients,
@@ -83,8 +80,7 @@ def main():
     flight_path = os.path.join(args.out, "flight.jsonl")
     report_path = os.path.join(args.out, "fleet.html")
     with obs.observe(trace_path=trace_path, metrics_path=metrics_path,
-                     flight_path=flight_path, report_path=report_path,
-                     kernel_profile=args.kernel_profile) as ob:
+                     flight_path=flight_path, report_path=report_path) as ob:
         history = api.run(data, cfg, scenario=scenario)
 
     errors = validate_chrome_trace(json.load(open(trace_path)))

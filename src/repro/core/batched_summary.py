@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import repro.obs as obs
 from repro.core.coreset import coreset_indices
 from repro.core.summary import (
     label_distribution,
@@ -240,13 +241,14 @@ class BatchedSummaryEngine:
         at most ``max_batch`` clients.
         """
         groups: dict[tuple, list] = {}
-        for cid, feats, labels, valid, key in items:
-            feats = np.asarray(feats, np.float32)
-            labels = np.asarray(labels, np.int32)
-            valid = np.asarray(valid, bool)
-            b = bucket_size(feats.shape[0])
-            groups.setdefault((b, feats.shape[1:]), []).append(
-                (cid, feats, labels, valid, np.asarray(key)))
+        with obs.span("summary/load"):
+            for cid, feats, labels, valid, key in items:
+                feats = np.asarray(feats, np.float32)
+                labels = np.asarray(labels, np.int32)
+                valid = np.asarray(valid, bool)
+                b = bucket_size(feats.shape[0])
+                groups.setdefault((b, feats.shape[1:]), []).append(
+                    (cid, feats, labels, valid, np.asarray(key)))
 
         out: dict[int, SummaryResult] = {}
         for (b, fs), group in groups.items():
@@ -273,12 +275,13 @@ class BatchedSummaryEngine:
         for b, cids in groups.items():
             for lo in range(0, len(cids), self.max_batch):
                 chunk = []
-                for c in cids[lo:lo + self.max_batch]:
-                    feats, labels, valid = load_fn(c)
-                    chunk.append((c, np.asarray(feats, np.float32),
-                                  np.asarray(labels, np.int32),
-                                  np.asarray(valid, bool),
-                                  np.asarray(key_fn(c))))
+                with obs.span("summary/load"):
+                    for c in cids[lo:lo + self.max_batch]:
+                        feats, labels, valid = load_fn(c)
+                        chunk.append((c, np.asarray(feats, np.float32),
+                                      np.asarray(labels, np.int32),
+                                      np.asarray(valid, bool),
+                                      np.asarray(key_fn(c))))
                 self._dispatch(chunk, b, chunk[0][1].shape[1:], out)
         return out
 
@@ -286,19 +289,20 @@ class BatchedSummaryEngine:
                   out: dict[int, SummaryResult]) -> None:
         m = len(chunk)
         mp = bucket_size(m, base=1)    # pad the client axis too: one trace
-        feats = np.zeros((mp, b, *fs), np.float32)
-        labels = np.zeros((mp, b), np.int32)
-        valid = np.zeros((mp, b), bool)
-        key_shape = chunk[0][4].shape
-        keys = np.zeros((mp, *key_shape), chunk[0][4].dtype)
-        for i, (_cid, f, l, v, k) in enumerate(chunk):
-            n = f.shape[0]
-            feats[i, :n] = f
-            labels[i, :n] = l
-            valid[i, :n] = v
-            keys[i] = k
-        args = (jnp.asarray(feats), jnp.asarray(labels), jnp.asarray(valid),
-                jnp.asarray(keys))
+        with obs.span("summary/assemble", slots=mp * b) as sp:
+            feats = np.zeros((mp, b, *fs), np.float32)
+            labels = np.zeros((mp, b), np.int32)
+            valid = np.zeros((mp, b), bool)
+            key_shape = chunk[0][4].shape
+            keys = np.zeros((mp, *key_shape), chunk[0][4].dtype)
+            for i, (_cid, f, l, v, k) in enumerate(chunk):
+                n = f.shape[0]
+                feats[i, :n] = f
+                labels[i, :n] = l
+                valid[i, :n] = v
+                keys[i] = k
+            sp.annotate(filled=int(valid.sum()))
+        args = obs.device_put("summary/put", (feats, labels, valid, keys))
 
         # AOT-compile per shape so compile time never lands in the timed
         # dispatch and the first chunk is not computed twice
@@ -307,14 +311,15 @@ class BatchedSummaryEngine:
         if exec_ is None:
             exec_ = self._fn.lower(*args).compile()
             self._execs[shape_key] = exec_
-        t0 = time.perf_counter()
-        summaries, lds = jax.block_until_ready(exec_(*args))
-        dt = time.perf_counter() - t0
+        with obs.span("summary/execute"):
+            t0 = time.perf_counter()
+            summaries, lds = jax.block_until_ready(exec_(*args))
+            dt = time.perf_counter() - t0
+            s_np, ld_np = np.asarray(summaries), np.asarray(lds)
 
         self.stats.clients += m
         self.stats.dispatches += 1
         self.stats.wall_s += dt
         per_client = dt / m
-        s_np, ld_np = np.asarray(summaries), np.asarray(lds)
         for i, (cid, *_rest) in enumerate(chunk):
             out[cid] = SummaryResult(s_np[i], ld_np[i], per_client)

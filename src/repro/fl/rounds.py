@@ -503,33 +503,41 @@ class RoundContext:
                 # online maintenance: assign-only for the drifted set; rows
                 # keep fleet indexing (zeros for absent clients) so the
                 # maintainer's state stays aligned under churn
+                with obs.span("recluster/gather"):
+                    dense = np.asarray(self.registry.dense(), np.float32)
+                    live = self.registry.has_mask() & active
                 self.maintainer.refresh(
-                    np.asarray(self.registry.dense(), np.float32),
-                    np.asarray(drifted, np.int64),
-                    jax.random.PRNGKey(cfg.seed + rnd),
-                    live=self.registry.has_mask() & active)
+                    dense, np.asarray(drifted, np.int64),
+                    jax.random.PRNGKey(cfg.seed + rnd), live=live)
                 if self.maintainer.assignment is not None:
                     self.assignment = self.maintainer.assignment
                     self.num_clusters = cfg.num_clusters
             else:
-                have_ids = np.flatnonzero(self.registry.has_mask() & active)
-                X = jnp.asarray(self.registry.matrix_rows(have_ids),
-                                jnp.float32)
+                with obs.span("recluster/gather"):
+                    have_ids = np.flatnonzero(self.registry.has_mask()
+                                              & active)
+                    rows = np.asarray(self.registry.matrix_rows(have_ids),
+                                      np.float32)
+                (X,) = obs.device_put("recluster/put", (rows,))
                 assignment = np.full(spec.num_clients, -1, np.int64)
-                if cfg.clustering in ("kmeans", "minibatch"):
-                    cluster_fn = (minibatch_kmeans
-                                  if cfg.clustering == "minibatch" else kmeans)
-                    res = cluster_fn(X, cfg.num_clusters,
-                                     jax.random.PRNGKey(cfg.seed + rnd),
-                                     use_kernel=self.use_kernel)
-                    assignment[have_ids] = np.asarray(res.assignment, np.int64)
-                    self.num_clusters = cfg.num_clusters
-                else:
-                    med = float(jnp.median(jnp.sqrt(
-                        jnp.sum(jnp.square(X - X.mean(0)), -1))))
-                    res = dbscan(X, eps=med * 0.5, min_samples=3)
-                    assignment[have_ids] = np.asarray(res.labels, np.int64)
-                    self.num_clusters = max(int(res.num_clusters), 1)
+                with obs.span("recluster/fit"):
+                    if cfg.clustering in ("kmeans", "minibatch"):
+                        cluster_fn = (minibatch_kmeans
+                                      if cfg.clustering == "minibatch"
+                                      else kmeans)
+                        res = cluster_fn(X, cfg.num_clusters,
+                                         jax.random.PRNGKey(cfg.seed + rnd),
+                                         use_kernel=self.use_kernel)
+                        assignment[have_ids] = np.asarray(res.assignment,
+                                                          np.int64)
+                        self.num_clusters = cfg.num_clusters
+                    else:
+                        med = float(jnp.median(jnp.sqrt(
+                            jnp.sum(jnp.square(X - X.mean(0)), -1))))
+                        res = dbscan(X, eps=med * 0.5, min_samples=3)
+                        assignment[have_ids] = np.asarray(res.labels,
+                                                          np.int64)
+                        self.num_clusters = max(int(res.num_clusters), 1)
                 self.assignment = assignment
             dt = time.perf_counter() - t0
             self._meters.add("cluster", dt)

@@ -17,14 +17,23 @@ stays off the clocks the paper measures) or a live one installed with
 Instrumented code never holds the observer: it calls the module-level
 ``span`` / ``instant`` / ``metrics`` helpers, which read the *current*
 observer at call time, so enabling observability is one call with no
-plumbing.  ``kernel_span`` additionally opens a ``jax.profiler``
-``TraceAnnotation`` around accelerator dispatches when the observer was
-enabled with ``kernel_profile=True`` — the annotations show up inside
-XLA device traces captured with ``jax.profiler.trace``.
+plumbing.
+
+While a JAX profiler session is active (``jax.profiler.start_trace`` /
+``jax.profiler.trace``), every ``span`` is recorded whether or not an
+observer is enabled: it enters a ``TraceAnnotation`` of its name, so it
+lands on the host plane of the device trace, and it appends its event
+(name, duration, args) to the enabled observer's tracer or, with none
+enabled, to the process-level record that ``profiled()`` returns.
+``device_put`` makes a host→device copy explicit and counts its bytes
+on a span of its own.
 """
 from __future__ import annotations
 
 import contextlib
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import (  # noqa: F401
     Counter,
@@ -59,14 +68,12 @@ class Observer:
     ...)`` / ``observe(report_path=...)`` / ``enable(flight=True)``) —
     provenance records are opt-in on top of tracing."""
 
-    __slots__ = ("tracer", "metrics", "flight", "kernel_profile")
+    __slots__ = ("tracer", "metrics", "flight")
 
-    def __init__(self, tracer=None, metrics=None, flight=None,
-                 kernel_profile: bool = False):
+    def __init__(self, tracer=None, metrics=None, flight=None):
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics if metrics is not None else NULL_REGISTRY
         self.flight = flight if flight is not None else NULL_RECORDER
-        self.kernel_profile = bool(kernel_profile)
 
     @property
     def enabled(self) -> bool:
@@ -75,6 +82,8 @@ class Observer:
 
 DISABLED = Observer()
 _current = DISABLED
+# spans recorded under a profiler session with no observer enabled
+_PROFILED = Tracer()
 
 
 def current() -> Observer:
@@ -82,7 +91,7 @@ def current() -> Observer:
     return _current
 
 
-def enable(kernel_profile: bool = False, flight: bool = False,
+def enable(flight: bool = False,
            flight_path: str | None = None) -> Observer:
     """Install (and return) a fresh live observer.  ``flight=True`` (or
     a ``flight_path``) arms the selection-provenance flight recorder;
@@ -95,8 +104,7 @@ def enable(kernel_profile: bool = False, flight: bool = False,
             os.makedirs(os.path.dirname(flight_path) or ".",
                         exist_ok=True)
         rec = FlightRecorder(flight_path)
-    _current = Observer(Tracer(), MetricRegistry(), flight=rec,
-                        kernel_profile=kernel_profile)
+    _current = Observer(Tracer(), MetricRegistry(), flight=rec)
     return _current
 
 
@@ -111,7 +119,7 @@ def disable() -> Observer:
 
 @contextlib.contextmanager
 def observe(trace_path: str | None = None, metrics_path: str | None = None,
-            kernel_profile: bool = False, flight_path: str | None = None,
+            flight_path: str | None = None,
             report_path: str | None = None, flight: bool = False):
     """Scoped observability: enable on entry; on exit restore the
     disabled default and write the requested artifacts (Chrome trace
@@ -119,8 +127,7 @@ def observe(trace_path: str | None = None, metrics_path: str | None = None,
     self-contained HTML fleet dashboard).  ``flight_path`` or
     ``report_path`` (which needs the records) arms the flight
     recorder."""
-    ob = enable(kernel_profile=kernel_profile,
-                flight=flight or report_path is not None,
+    ob = enable(flight=flight or report_path is not None,
                 flight_path=flight_path)
     try:
         yield ob
@@ -143,8 +150,35 @@ def observe(trace_path: str | None = None, metrics_path: str | None = None,
 
 def span(name: str, cat: str = "server", lane: int = LANE_CRITICAL,
          **args):
-    """A span on the current tracer (the shared no-op when disabled)."""
-    return _current.tracer.span(name, cat=cat, lane=lane, **args)
+    """A span on the current tracer; under a profiler session also an
+    annotation of the device trace, recorded by ``profiled()`` when no
+    observer is enabled.  The shared no-op when neither is on."""
+    profiling = TraceAnnotation.is_enabled()
+    if _current.enabled:
+        return _current.tracer.span(name, cat=cat, lane=lane,
+                                    profile=profiling, **args)
+    if profiling:
+        return _PROFILED.span(name, cat=cat, lane=lane, profile=True, **args)
+    return NULL_SPAN
+
+
+def profiled() -> Tracer:
+    """The spans recorded under profiler sessions while no observer was
+    enabled, in this process (``profiled().events``)."""
+    return _PROFILED
+
+
+def device_put(name: str, arrays: tuple, sharding=None) -> tuple:
+    """Copy host ``arrays`` to the device (``sharding``: as
+    ``jax.device_put``) inside span ``name``, whose ``bytes`` arg is their
+    total size.  A recording span waits for the copy, so its duration is
+    the copy's; otherwise nothing blocks here (whatever reads the arrays
+    waits for them)."""
+    with span(name, bytes=sum(int(a.nbytes) for a in arrays)) as sp:
+        out = jax.device_put(tuple(arrays), sharding)
+        if sp is not NULL_SPAN:
+            jax.block_until_ready(out)
+    return out
 
 
 def instant(name: str, cat: str = "server", lane: int = LANE_CRITICAL,
@@ -170,46 +204,3 @@ def recorder():
 
 def enabled() -> bool:
     return _current.enabled
-
-
-class _AnnotatedSpan:
-    """A tracer span + a ``jax.profiler.TraceAnnotation`` entered
-    together — the host-side span and the device-trace annotation cover
-    the same dispatch."""
-
-    __slots__ = ("_span", "_ann")
-
-    def __init__(self, sp, ann):
-        self._span = sp
-        self._ann = ann
-
-    def __enter__(self):
-        self._span.__enter__()
-        self._ann.__enter__()
-        return self._span
-
-    def __exit__(self, exc_type, exc, tb):
-        self._ann.__exit__(exc_type, exc, tb)
-        self._span.__exit__(exc_type, exc, tb)
-
-    def annotate(self, **kw) -> None:
-        self._span.annotate(**kw)
-
-
-def kernel_span(name: str, **args):
-    """Span around an accelerator dispatch.  With ``kernel_profile``
-    enabled, additionally annotates the XLA device timeline via
-    ``jax.profiler.TraceAnnotation`` (visible in traces captured with
-    ``jax.profiler.trace``); otherwise it is a plain host span — and the
-    shared no-op when observability is off."""
-    ob = _current
-    if not ob.enabled:
-        return NULL_SPAN
-    sp = ob.tracer.span(name, cat="kernel", **args)
-    if ob.kernel_profile:
-        try:
-            from jax.profiler import TraceAnnotation
-        except ImportError:          # profiler unavailable: host span only
-            return sp
-        return _AnnotatedSpan(sp, TraceAnnotation(name))
-    return sp

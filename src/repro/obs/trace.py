@@ -22,10 +22,17 @@ and ``enabled`` is ``False`` so hot loops can skip even the call.  An
 *enabled* tracer's span costs two clock reads and one dict append —
 ``benchmarks/bench_obs.py`` measures both and asserts the end-to-end
 overhead budget (<2 % of the sync critical path).
+
+A span opened with ``profile=True`` (``repro.obs.span`` does so while a
+JAX profiler session is active) also enters a
+``jax.profiler.TraceAnnotation`` of its name, so the same span lands on
+the host plane of the profiler's device trace, on that trace's clock.
 """
 from __future__ import annotations
 
 import time
+
+from jax.profiler import TraceAnnotation
 
 # Chrome tid values for the two execution lanes (names published via
 # thread-metadata events so Perfetto labels the rows).
@@ -37,24 +44,29 @@ LANE_NAMES = {LANE_CRITICAL: "round-critical", LANE_BACKGROUND: "background"}
 class Span:
     """One in-flight span; records its complete event on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_start")
+    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_start", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int,
-                 args: dict | None):
+                 args: dict | None, profile: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.tid = tid
         self.args = args
         self._start = 0.0
+        self._ann = TraceAnnotation(name) if profile else None
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._start = self._tracer._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         tr = self._tracer
         end = tr._clock()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         ev = {"name": self.name, "cat": self.cat, "ph": "X",
               "ts": (self._start - tr._t0) * 1e6,
               "dur": (end - self._start) * 1e6,
@@ -106,8 +118,9 @@ class Tracer:
     # -- recording -----------------------------------------------------
 
     def span(self, name: str, cat: str = "server",
-             lane: int = LANE_CRITICAL, **args) -> Span:
-        return Span(self, name, cat, lane, args or None)
+             lane: int = LANE_CRITICAL, profile: bool = False,
+             **args) -> Span:
+        return Span(self, name, cat, lane, args or None, profile)
 
     def instant(self, name: str, cat: str = "server",
                 lane: int = LANE_CRITICAL, **args) -> None:
@@ -160,7 +173,8 @@ class NullTracer:
     pid = 0
 
     def span(self, name: str, cat: str = "server",
-             lane: int = LANE_CRITICAL, **args) -> _NullSpan:
+             lane: int = LANE_CRITICAL, profile: bool = False,
+             **args) -> _NullSpan:
         return NULL_SPAN
 
     def instant(self, name: str, cat: str = "server",

@@ -41,9 +41,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import repro.obs as obs
 from repro.core.scheduler import RefreshPolicy, batch_sym_kl
 from repro.stream.registry import StreamingSummaryRegistry
-from repro.utils.roofline import (
-    device_peaks, drift_scan_bytes, record_bandwidth,
-)
 from repro.utils.sharding import FLEET_RULES, fleet_mesh, make_spec
 
 
@@ -61,13 +58,18 @@ def _sym_kl_rows(p, q, eps: float = 1e-9):
                   + jnp.sum(q * jnp.log(q / p), axis=-1))
 
 
+def _chunk_spec(mesh: Mesh, rows: int, num_classes: int):
+    """A chunk's row-wise layout over the ``fleet`` mesh axis."""
+    return make_spec(("clients", None), (rows, num_classes), mesh,
+                     rules=FLEET_RULES)
+
+
 @functools.lru_cache(maxsize=64)
 def _drift_scan(mesh: Mesh, rows: int, num_classes: int):
     """Compiled chunk scan for a (mesh, chunk shape) — cached at module
     level so every registry instance with the same layout shares one
     compile (the differential tests build many registries)."""
-    spec = make_spec(("clients", None), (rows, num_classes), mesh,
-                     rules=FLEET_RULES)
+    spec = _chunk_spec(mesh, rows, num_classes)
     sharded = jax.shard_map(_sym_kl_rows, mesh=mesh,
                             in_specs=(spec, spec), out_specs=P(*spec[:1]))
     return jax.jit(sharded,
@@ -105,32 +107,32 @@ class ShardedSummaryRegistry(StreamingSummaryRegistry):
 
     def _drift(self, fresh: np.ndarray) -> np.ndarray:
         n, c = self.label_dists.shape
-        scan = _drift_scan(self.mesh, self.chunk_rows, c)
-        out = np.empty(n, np.float32)
         rows = self.chunk_rows
+        scan = _drift_scan(self.mesh, rows, c)
+        layout = NamedSharding(self.mesh, _chunk_spec(self.mesh, rows, c))
+        out = np.empty(n, np.float32)
         pad_p = pad_q = None
         observed = obs.enabled()
-        t_scan = time.perf_counter() if observed else 0.0
         chunk_fam = (obs.metrics().family("shard/scan_chunk_s",
                                           labels=("chunk",),
                                           kind="histogram")
                      if observed else None)
-        with obs.kernel_span("drift_scan", rows=n, classes=c,
-                             n_shards=self.n_shards,
-                             chunk_rows=rows) as sp:
+        with obs.span("drift_scan/chunks", cat="kernel", rows=n, classes=c,
+                      n_shards=self.n_shards, chunk_rows=rows) as sp:
             for start in range(0, n, rows):
                 stop = min(start + rows, n)
                 m = stop - start
                 t_chunk = time.perf_counter() if observed else 0.0
                 if m == rows:
-                    d = scan(self.label_dists[start:stop], fresh[start:stop])
+                    p, q = self.label_dists[start:stop], fresh[start:stop]
                 else:                       # tail chunk: zero-pad to shape
                     if pad_p is None:
                         pad_p = np.zeros((rows, c), np.float32)
                         pad_q = np.zeros((rows, c), np.float32)
                     pad_p[:m] = self.label_dists[start:stop]
                     pad_q[:m] = fresh[start:stop]
-                    d = scan(pad_p, pad_q)
+                    p, q = pad_p, pad_q
+                d = scan(*obs.device_put("drift_scan/put", (p, q), layout))
                 out[start:stop] = np.asarray(d)[:m]
                 if chunk_fam is not None:
                     # per-chunk scan time: a straggling shard region
@@ -140,15 +142,6 @@ class ShardedSummaryRegistry(StreamingSummaryRegistry):
                         time.perf_counter() - t_chunk)
                 self.scan_chunks += 1
             sp.annotate(chunks=-(-n // rows))
-        if observed:
-            # achieved vs roofline-predicted scan bandwidth (gauges),
-            # against the HBM of every device in the mesh
-            peaks = device_peaks(self.mesh.devices.flat[0].device_kind)
-            record_bandwidth(obs.metrics(), "kernel/drift_scan",
-                             drift_scan_bytes(n, c),
-                             time.perf_counter() - t_scan,
-                             peak_bw=(peaks.hbm_bw * self.n_shards
-                                      if peaks else None))
         # borderline band: device libm may differ from numpy by ~1 ulp, so
         # rows near the threshold are re-decided with the exact baseline
         # math — decisions match the streaming registry on any mesh

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import repro.obs as obs
 from repro.core.batched_summary import bucket_size
 from repro.core.kmeans import kmeans, pairwise_sq_dist
 
@@ -78,18 +79,21 @@ class OnlineClusterMaintainer:
         """Running J under the current (frozen) centroids."""
         return float(self.dists.sum()) if self.dists is not None else np.inf
 
-    def _assign(self, x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    def _assign(self, x: np.ndarray, rows=None
+                ) -> tuple[np.ndarray, np.ndarray]:
+        """Nearest centroid and its squared distance for ``x[rows]`` (all
+        of ``x`` when ``rows`` is None)."""
         # pad the row axis to a power-of-two bucket so the jitted assign
         # compiles O(log N) times total, not once per drift-set size
-        m = x.shape[0]
-        b = bucket_size(m)
-        xp = np.zeros((b, x.shape[1]), np.float32)
-        xp[:m] = x
-        a, d = _assign_fn(jnp.asarray(xp), jnp.asarray(self.centroids),
-                          self.policy.use_kernel)
-        jax.block_until_ready(d)
-        return (np.asarray(a[:m], np.int64).copy(),
-                np.asarray(d[:m]).copy())
+        with obs.span("recluster/gather"):
+            m = x.shape[0] if rows is None else len(rows)
+            xp = np.zeros((bucket_size(m), x.shape[1]), np.float32)
+            xp[:m] = x if rows is None else x[rows]
+        xp, cents = obs.device_put("recluster/put", (xp, self.centroids))
+        with obs.span("recluster/fit"):
+            a, d = _assign_fn(xp, cents, self.policy.use_kernel)
+            return (np.asarray(a[:m], np.int64).copy(),
+                    np.asarray(d[:m]).copy())
 
     def _live_mask(self, n: int, live) -> np.ndarray:
         if live is None:
@@ -102,12 +106,17 @@ class OnlineClusterMaintainer:
         centroid on the origin); every row still gets an assignment so
         indexing stays stable, but absent rows carry zero inertia."""
         live = self._live_mask(x.shape[0], live)
-        res = kmeans(jnp.asarray(x[live], jnp.float32), self.k, key,
-                     max_iters=self.policy.max_iters,
-                     use_kernel=self.policy.use_kernel)
-        self.centroids = np.array(res.centroids)       # writable copy
+        with obs.span("recluster/gather"):
+            rows = np.asarray(x[live], np.float32)
+        (rows,) = obs.device_put("recluster/put", (rows,))
+        with obs.span("recluster/fit"):
+            res = kmeans(rows, self.k, key,
+                         max_iters=self.policy.max_iters,
+                         use_kernel=self.policy.use_kernel)
+            self.centroids = np.array(res.centroids)   # writable copy
+            fit_assignment = np.asarray(res.assignment, np.int64)
         self.assignment, self.dists = self._assign(x)
-        self.assignment[live] = np.asarray(res.assignment, np.int64)
+        self.assignment[live] = fit_assignment
         self.dists[~live] = 0.0
         self.last_full_inertia = float(res.inertia)    # live-row objective
         self.full_fits += 1
@@ -130,7 +139,7 @@ class OnlineClusterMaintainer:
 
         drifted = np.asarray(drifted_ids, np.int64)
         if drifted.size:
-            a, d = self._assign(x[drifted])
+            a, d = self._assign(x, drifted)
             self.assignment[drifted] = a
             self.dists[drifted] = d
         self.dists[~live] = 0.0          # absent rows carry no inertia

@@ -75,8 +75,8 @@ def cm_update_batch(labels, valid, spec: SketchSpec,
     m, n = labels.shape
     a, b = spec.hash_params
     seg = np.repeat(np.arange(m, dtype=np.int32), n)
-    with obs.kernel_span("sketch_update", clients=m, items=m * n,
-                         kernel=bool(use_kernel)):
+    with obs.span("sketch_update", cat="kernel", clients=m, items=m * n,
+                  kernel=bool(use_kernel)):
         if use_kernel:
             from repro.kernels.ops import sketch_update
             out = sketch_update(labels.reshape(-1), seg, valid.reshape(-1),

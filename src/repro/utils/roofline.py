@@ -7,8 +7,7 @@ this module turns those into the three roofline terms per chip:
     memory     = HLO_bytes      / (chips * HBM_BW)
     collective = collective_B   / (chips * ICI_BW)
 
-Peaks live in ``PEAKS``, keyed by ``jax.Device.device_kind``.  A device
-that is not in the table has no peak: nothing divides by one for it.
+The peaks are the dry-run target's, a TPU v5e (``V5E``).
 """
 from __future__ import annotations
 
@@ -27,19 +26,12 @@ class DevicePeaks:
 # 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over 4 links.
 V5E = DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
                   hbm_bytes=16 * 1024**3)
-PEAKS: dict[str, DevicePeaks] = {"TPU v5 lite": V5E, "TPU v5e": V5E}
 
 # the dry-run's target (production v5e meshes)
 PEAK_FLOPS = V5E.flops
 HBM_BW = V5E.hbm_bw
 ICI_BW = V5E.ici_bw
 HBM_PER_CHIP = V5E.hbm_bytes
-
-
-def device_peaks(device_kind: str) -> DevicePeaks | None:
-    """Published peaks of ``device_kind``; None for a device not in the
-    table (the CPU among them)."""
-    return PEAKS.get(device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,29 +103,6 @@ class Roofline:
             "bytes_per_device": self.bytes_per_device,
             "fits_hbm": self.bytes_per_device <= HBM_PER_CHIP,
         }
-
-
-def drift_scan_bytes(rows: int, num_classes: int,
-                     dtype_bytes: int = 4) -> float:
-    """HBM traffic of one drift-scan pass: stream the stored and fresh
-    ``[rows, C]`` label-dist arenas in, one ``[rows]`` drift column out."""
-    return float(rows) * (2.0 * num_classes + 1.0) * dtype_bytes
-
-
-def record_bandwidth(metrics, name: str, nbytes: float, seconds: float,
-                     peak_bw: float | None = HBM_BW) -> float:
-    """Record achieved vs roofline-predicted bandwidth for one measured
-    pass as gauges (``<name>/achieved_gbs``, ``<name>/predicted_gbs``,
-    ``<name>/efficiency``) on a metric registry; returns the achieved
-    bytes/s.  ``peak_bw=None`` (a device with no published peak) records
-    the achieved figure only.
-    """
-    achieved = nbytes / seconds if seconds > 0 else float("nan")
-    metrics.gauge(f"{name}/achieved_gbs").set(achieved / 1e9)
-    if peak_bw is not None:
-        metrics.gauge(f"{name}/predicted_gbs").set(peak_bw / 1e9)
-        metrics.gauge(f"{name}/efficiency").set(achieved / peak_bw)
-    return achieved
 
 
 def dense_model_flops(num_params: int, tokens: int) -> float:

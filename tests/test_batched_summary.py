@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.obs as obs
 from repro.core import (
     BatchedSummaryEngine, RefreshPolicy, SummaryRegistry,
     batched_per_label_mean, batched_pxy_histogram, bucket_size,
@@ -148,3 +149,68 @@ def test_max_batch_chunks_dispatches():
     engine.summarize(_items(data))
     assert engine.stats.clients == 12
     assert engine.stats.dispatches >= 6     # ceil(group/2) per bucket
+
+
+# ---------------------------------------------------------------------------
+# the engine's spans under a profiler session: copies, padding, nesting
+
+
+def _profiled(fn, trace_dir):
+    """Run ``fn`` under a profiler session; the spans it recorded."""
+    n0 = len(obs.profiled().events)
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return obs.profiled().events[n0:]
+
+
+@pytest.mark.parametrize("max_batch", [3, 256])
+def test_profiled_rounds_count_copies_and_padding(data, max_batch, tmp_path):
+    """Two summary rounds under a profiler: every ``summary/put`` carries
+    the bytes of its padded batch, ``summary/assemble`` its slots and
+    real samples, as reckoned here from the bucket arithmetic, and the
+    engine's spans nest inside the caller's."""
+    spec = data.spec
+    engine = BatchedSummaryEngine("py", spec.num_classes, max_batch=max_batch)
+    ids = list(range(spec.num_clients))
+    sample_bytes = int(np.prod(spec.feature_shape)) * 4 + 4 + 1  # f32, i32, bool
+
+    def two_rounds():
+        for rnd in range(2):
+            with obs.span("client_summaries", round=rnd):
+                engine.summarize_clients(
+                    ids, data.sizes, data.client_data,
+                    lambda c: jax.random.PRNGKey(rnd * 1000 + c))
+
+    events = _profiled(two_rounds, tmp_path)
+    by_bucket: dict = {}
+    for c in ids:
+        by_bucket.setdefault(bucket_size(int(data.sizes[c])), []).append(c)
+    want_bytes = want_slots = want_puts = 0
+    for b, cids in by_bucket.items():
+        for lo in range(0, len(cids), max_batch):
+            mp = bucket_size(len(cids[lo:lo + max_batch]), base=1)
+            want_bytes += mp * b * sample_bytes + mp * 2 * 4  # + uint32 keys
+            want_slots += mp * b
+            want_puts += 1
+
+    def named(name):
+        return [e for e in events if e["name"] == name]
+
+    puts = named("summary/put")
+    assert len(puts) == 2 * want_puts == len(named("summary/execute"))
+    assert sum(e["args"]["bytes"] for e in puts) == 2 * want_bytes
+    assemble = named("summary/assemble")
+    assert sum(e["args"]["slots"] for e in assemble) == 2 * want_slots
+    assert sum(e["args"]["filled"] for e in assemble) == \
+        2 * int(data.sizes.sum())
+    assert len(named("summary/load")) == 2 * want_puts
+    parents = named("client_summaries")
+    assert len(parents) == 2
+    for e in events:
+        if e["name"].startswith("summary/"):
+            assert any(p["ts"] <= e["ts"]
+                       and e["ts"] + e["dur"] <= p["ts"] + p["dur"]
+                       for p in parents), e["name"]

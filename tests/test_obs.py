@@ -20,9 +20,6 @@ from repro.obs.export import (
     metrics_records, read_metrics_jsonl, validate_chrome_trace,
     write_metrics_jsonl, write_trace,
 )
-from repro.utils.roofline import (
-    HBM_BW, device_peaks, drift_scan_bytes, record_bandwidth,
-)
 
 # the deterministic keys of the 24-seed differential pin — telemetry
 # must never move them, enabled or not.  (``sim_time`` is pinned there
@@ -165,7 +162,7 @@ def test_disabled_is_the_default_and_noop():
     assert obs.current() is obs.DISABLED
     assert not obs.enabled()
     assert obs.span("x", round=1) is NULL_SPAN
-    assert obs.kernel_span("k", rows=4) is NULL_SPAN
+    assert obs.span("k", cat="kernel", rows=4) is NULL_SPAN
     assert obs.metrics() is NULL_REGISTRY
     with obs.span("x") as sp:
         sp.annotate(n=1)                       # all no-ops, nothing raised
@@ -189,7 +186,7 @@ def test_observe_scopes_and_writes_artifacts(tmp_path):
         obs.counter_sample("depth", 4.0)
         obs.metrics().counter("c").inc(2)
         obs.metrics().histogram("h_s").record(1e-3)
-        ks = obs.kernel_span("k", rows=8)
+        ks = obs.span("k", cat="kernel", rows=8)
         assert ks is not NULL_SPAN
         with ks:
             pass
@@ -271,55 +268,119 @@ def test_tracer_absorb_merges_timelines():
 
 
 # ---------------------------------------------------------------------------
-# roofline cross-check gauges
+# spans on the profiler's clock: annotations + the profiled record
 
 
-def test_record_bandwidth_gauges():
-    r = MetricRegistry()
-    nbytes = drift_scan_bytes(100_000, 10)
-    assert nbytes == 100_000 * 21 * 4
-    achieved = record_bandwidth(r, "kernel/drift_scan", nbytes, 1e-3)
-    assert achieved == pytest.approx(nbytes / 1e-3)
-    assert r.gauge("kernel/drift_scan/achieved_gbs").value == \
-        pytest.approx(achieved / 1e9)
-    assert r.gauge("kernel/drift_scan/predicted_gbs").value == \
-        pytest.approx(HBM_BW / 1e9)
-    assert r.gauge("kernel/drift_scan/efficiency").value == \
-        pytest.approx(achieved / HBM_BW)
+def _xplane_host_events(trace_dir) -> dict:
+    """{name: [(start_ns, dur_ns), ...]} of the host-plane events in the
+    ``.xplane.pb`` a profiler session wrote under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (float(e.start_ns), float(e.duration_ns)))
+    return out
 
 
-def test_peaks_are_keyed_by_device_kind():
-    v5e = device_peaks("TPU v5 lite")
-    assert v5e.flops == 197e12 and v5e.hbm_bw == HBM_BW == 819e9
-    assert device_peaks("cpu") is None
+def test_no_observer_no_profiler_records_nothing():
+    import jax
+    n0 = len(obs.profiled().events)
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert obs.span("summary/put", bytes=8) is NULL_SPAN
+    with obs.span("x", round=1) as sp:
+        sp.annotate(n=2)
+    host = np.arange(6, dtype=np.float32)
+    (dev,) = obs.device_put("x/put", (host,))
+    assert isinstance(dev, jax.Array)
+    np.testing.assert_array_equal(np.asarray(dev), host)
+    assert len(obs.profiled().events) == n0
 
 
-def test_record_bandwidth_no_efficiency_for_unknown_device():
-    r = MetricRegistry()
-    record_bandwidth(r, "kernel/drift_scan", 1e6, 1e-3,
-                     peak_bw=device_peaks("cpu"))
-    assert r.gauge("kernel/drift_scan/achieved_gbs").value == \
-        pytest.approx(1.0)
-    assert r.get("kernel/drift_scan/efficiency") is None
-    assert r.get("kernel/drift_scan/predicted_gbs") is None
+def test_profiled_span_in_device_trace_and_record(tmp_path):
+    """Under a profiler session with no observer, a span is both an
+    annotation on the trace's host plane and an event of ``profiled()``,
+    and the two durations agree."""
+    import time
+
+    import jax
+    n0 = len(obs.profiled().events)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("t_outer", cat="test", round=4) as sp:
+            with obs.span("t_inner"):
+                time.sleep(0.03)
+            time.sleep(0.01)
+            sp.annotate(n=3)
+    finally:
+        jax.profiler.stop_trace()
+    with obs.span("t_after"):
+        pass
+    recorded = {e["name"]: e for e in obs.profiled().events[n0:]}
+    assert set(recorded) == {"t_outer", "t_inner"}
+    assert recorded["t_outer"]["args"] == {"round": 4, "n": 3}
+    host = _xplane_host_events(tmp_path)
+    for name in ("t_outer", "t_inner"):
+        ((_start, dur_ns),) = host[name]
+        assert dur_ns / 1e3 == pytest.approx(recorded[name]["dur"], rel=0.1)
+    (o_start, o_dur), = host["t_outer"]
+    (i_start, i_dur), = host["t_inner"]
+    assert o_start <= i_start and i_start + i_dur <= o_start + o_dur
+    ev_o, ev_i = recorded["t_outer"], recorded["t_inner"]
+    assert ev_o["ts"] <= ev_i["ts"]
+    assert ev_i["ts"] + ev_i["dur"] <= ev_o["ts"] + ev_o["dur"]
 
 
-def test_drift_scan_on_cpu_writes_no_efficiency_gauge():
-    """The sharded registry looks its device up in the peaks table; the
-    CPU has no entry, so the scan records achieved bandwidth only."""
-    from repro.core.scheduler import RefreshPolicy
-    from repro.shard import ShardedSummaryRegistry
-    n, c = 64, 5
-    rs = np.random.RandomState(0)
-    ld = rs.dirichlet([0.5] * c, n).astype(np.float32)
-    reg = ShardedSummaryRegistry(n, RefreshPolicy(10 ** 6, 0.05),
-                                 num_classes=c, chunk_rows=32)
-    reg.update_batch(np.arange(n), 0, ld, ld)
+def test_profiled_span_goes_to_the_enabled_observer(tmp_path):
+    import jax
+    n0 = len(obs.profiled().events)
     with obs.observe() as ob:
-        reg.stale_mask(1, ld)
-    assert ob.metrics.get("kernel/drift_scan/achieved_gbs") is not None
-    assert ob.metrics.get("kernel/drift_scan/efficiency") is None
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("t_observed"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+    assert "t_observed" in ob.tracer.span_names()
+    assert len(obs.profiled().events) == n0
+    assert "t_observed" in _xplane_host_events(tmp_path)
 
+
+@pytest.mark.parametrize("arrays", [
+    ((3, 5, np.float32), (3, np.int32)),
+    ((4, 2, 2, np.float32), (4, 2, bool), (4, 2, np.uint32)),
+    ((0, 7, np.float32),),
+], ids=["two", "three", "empty"])
+def test_device_put_counts_the_bytes_it_copies(arrays):
+    """A recording ``device_put`` span carries the copied arrays' total
+    bytes, reckoned here from their shapes and item sizes."""
+    import jax
+    host = [np.ones(a[:-1], a[-1]) for a in arrays]
+    want = sum(int(np.prod(a[:-1])) * np.dtype(a[-1]).itemsize
+               for a in arrays)
+    with obs.observe() as ob:
+        dev = obs.device_put("t/put", tuple(host))
+    (ev,) = [e for e in ob.tracer.events if e["name"] == "t/put"]
+    assert ev["args"] == {"bytes": want}
+    assert len(dev) == len(host)
+    for d, h in zip(dev, host):
+        assert isinstance(d, jax.Array) and d.dtype == h.dtype
+        np.testing.assert_array_equal(np.asarray(d), h)
+
+
+def test_device_put_keeps_the_sharding():
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:1]), ("fleet",))
+    layout = NamedSharding(mesh, P("fleet", None))
+    (dev,) = obs.device_put("t/put", (np.zeros((4, 3), np.float32),), layout)
+    assert dev.sharding == layout
 
 # ---------------------------------------------------------------------------
 # end-to-end federation observability

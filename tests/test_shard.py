@@ -12,6 +12,7 @@ import pytest
 
 import jax.numpy as jnp
 
+import repro.obs as obs
 from repro.core import RefreshPolicy, kmeans, weighted_kmeans
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl import FLConfig, run_federated
@@ -191,3 +192,68 @@ def test_unknown_clustering_rejected():
                                        side=8, avg_samples=12), seed=0)
     with pytest.raises(ValueError, match="unknown clustering"):
         run_federated(data, FLConfig(rounds=1, clustering="nope"))
+
+
+# ---------------------------------------------------------------------------
+# the round path's copies under a profiler session
+
+
+def _within(child, parents) -> bool:
+    return any(p["ts"] <= child["ts"]
+               and child["ts"] + child["dur"] <= p["ts"] + p["dur"]
+               for p in parents)
+
+
+@pytest.mark.parametrize("clustering", ["kmeans", "online"])
+def test_profiled_rounds_count_every_copy(clustering, tmp_path):
+    """Two sync rounds under a profiler: every host→device copy on the
+    round path is a ``*/put`` span whose bytes match those reckoned here
+    from the scan's chunk arenas, the summary batches and the clustering
+    input, and each stage's child spans nest inside it.  Round 1 has no
+    drift, so its only copies are the scan's."""
+    from repro.core import bucket_size
+    n, c, k, chunk = 24, 5, 3, 10
+    data = FederatedDataset(small_spec(num_clients=n, num_classes=c, side=8,
+                                       avg_samples=20), seed=5)
+    cfg = FLConfig(rounds=2, clients_per_round=4, local_steps=1,
+                   summary="py", registry="sharded", shard_chunk_rows=chunk,
+                   clustering=clustering, num_clusters=k, eval_every=2,
+                   seed=1)
+    n0 = len(obs.profiled().events)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        h = run_federated(data, cfg)
+    finally:
+        jax.profiler.stop_trace()
+    assert h["refreshes"] == [n, n]            # round 1: nothing stale
+    events = obs.profiled().events[n0:]
+
+    def named(name):
+        return [e for e in events if e["name"] == name]
+
+    def put_bytes(name):
+        return sum(e["args"]["bytes"] for e in named(name))
+
+    shards = len(jax.devices())
+    rows = -(-chunk // shards) * shards
+    chunks = -(-n // rows)
+    assert put_bytes("drift_scan/put") == 2 * chunks * 2 * rows * c * 4
+    sample_bytes = 8 * 8 * 1 * 4 + 4 + 1
+    by_bucket = np.bincount([bucket_size(int(s)) for s in data.sizes])
+    want = sum(bucket_size(m, base=1) * (b * sample_bytes + 2 * 4)
+               for b, m in enumerate(by_bucket) if m)
+    assert put_bytes("summary/put") == want
+    x_bytes = n * c * 4                        # the P(y) summaries, float32
+    if clustering == "online":                 # the fit, then one assign
+        x_bytes += bucket_size(n) * c * 4 + k * c * 4
+    assert put_bytes("recluster/put") == x_bytes
+    assert {e["name"] for e in events if e["name"].endswith("/put")} == {
+        "drift_scan/put", "summary/put", "recluster/put"}
+    for child, parent in (("drift_scan/put", "drift_scan/chunks"),
+                          ("drift_scan/chunks", "drift_scan"),
+                          ("drift_scan", "scan"),
+                          ("summary/", "client_summaries"),
+                          ("recluster/", "recluster")):
+        kids = [e for e in events if e["name"].startswith(child)]
+        assert kids and all(_within(e, named(parent)) for e in kids), child
+    assert len(named("select_devices")) == 2
