@@ -495,7 +495,17 @@ class RoundContext:
                       drifted: np.ndarray) -> float:
         """Unconditional clustering rebuild/refresh from the live registry
         (the caller owns the cadence: sync gating or the async staleness
-        policy).  Returns the wall seconds this rebuild took."""
+        policy).  Returns the wall seconds this rebuild took.
+
+        Full clustering (``kmeans``/``minibatch``/``dbscan``) copies the
+        registry's live ``dense()`` buffer to the device as it is, with no
+        host gather; when not every row is live (churn, inactive clients)
+        the live rows are picked on the device, in ``matrix_rows`` order.
+        The registry's buffer is the source of that asynchronous copy (on
+        the CPU backend the device array may alias it), so nothing may
+        write the registry until the assignment is read back below, and
+        no device array made from it outlives this call: this method is
+        synchronous and the clustering functions donate no buffer."""
         cfg, spec = self.cfg, self.spec
         with obs.span("recluster", round=rnd, n_drifted=int(len(drifted))):
             t0 = time.perf_counter()
@@ -513,12 +523,20 @@ class RoundContext:
                     self.assignment = self.maintainer.assignment
                     self.num_clusters = cfg.num_clusters
             else:
-                with obs.span("recluster/gather"):
+                with obs.span("recluster/gather") as sp:
                     have_ids = np.flatnonzero(self.registry.has_mask()
                                               & active)
-                    rows = np.asarray(self.registry.matrix_rows(have_ids),
-                                      np.float32)
+                    if have_ids.size:
+                        rows = np.asarray(self.registry.dense(), np.float32)
+                    else:   # nothing to cluster: as matrix_rows gives it
+                        rows = self.registry.matrix_rows(have_ids)
+                    # every row, in order: the buffer goes whole
+                    take = (0 if have_ids.size == rows.shape[0]
+                            else int(have_ids.size))
+                    sp.annotate(rows=int(have_ids.size), device_take=take)
                 (X,) = obs.device_put("recluster/put", (rows,))
+                if take:
+                    X = jnp.take(X, jnp.asarray(have_ids), axis=0)
                 assignment = np.full(spec.num_clients, -1, np.int64)
                 with obs.span("recluster/fit"):
                     if cfg.clustering in ("kmeans", "minibatch"):
