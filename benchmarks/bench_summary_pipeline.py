@@ -69,7 +69,7 @@ def run_fleet(num_clients: int = 512, methods=("py", "encoder", "pxy"),
     enc_params = build_cnn(CNNConfig(in_channels=1, feature_dim=32),
                            jax.random.PRNGKey(7))
     enc_fn = jax.jit(lambda x: cnn_apply(enc_params, x))
-    clients = [(c, *data.client_data(c), jax.random.PRNGKey(seed * 7 + c))
+    clients = [(c, *data.client_data(c), seed * 7 + c)
                for c in range(num_clients)]
 
     rows = []
@@ -77,10 +77,11 @@ def run_fleet(num_clients: int = 512, methods=("py", "encoder", "pxy"),
         # per-client path: one jitted dispatch per client (timed_summary
         # already excludes compiles via its warm call)
         per_client_s, per_summaries = 0.0, {}
-        for c, feats, labels, valid, key in clients:
+        for c, feats, labels, valid, seed_c in clients:
             s, _, dt = timed_summary(method, feats, labels, valid,
                                      spec.num_classes, encoder_fn=enc_fn,
-                                     coreset_k=32, bins=8, key=key)
+                                     coreset_k=32, bins=8,
+                                     key=jax.random.PRNGKey(seed_c))
             per_client_s += dt
             per_summaries[c] = s
         # batched engine: one dispatch per (bucket, chunk)

@@ -157,7 +157,7 @@ def kernel_phase(data, cfg) -> None:
     # one full dispatch: the 256 first clients of the 128-sample bucket
     ids = [c for c in range(data.spec.num_clients)
            if bucket_size(int(data.sizes[c])) == 128][:ctx.engine.max_batch]
-    items = [(c, *data.client_data(c), jax.random.PRNGKey(c)) for c in ids]
+    items = [(c, *data.client_data(c), c) for c in ids]
 
     main = ctx.engine.summarize(items)
     (exec_,) = ctx.engine._execs.values()

@@ -21,14 +21,21 @@ Per-client timings are recovered by amortizing the measured batch wall time
 uniformly over the clients in the dispatch, so the simulated clock and the
 ``SummaryRegistry`` refresh accounting are unchanged in expectation.
 
+Each client brings an integer PRNG seed, not a key: the seeds go to the
+device as one int32 array and the jitted dispatch makes the keys, so the
+host never waits on the device per client.
+
 Numerical contract: for every client, the batched result matches the
 per-client ``fl.client.timed_summary`` result (same bucket padding, same
-PRNG key ⇒ same coreset) to float tolerance — asserted by
-``tests/test_batched_summary.py``.
+seed ⇒ same key ⇒ same coreset) to float tolerance — asserted by
+``tests/test_batched_summary.py``.  A seed lies in ``[0, 2**31)``, the range
+in which the in-jit ``PRNGKey`` of an int32 equals
+``jax.random.PRNGKey(seed)`` bit for bit.
 """
 from __future__ import annotations
 
 import dataclasses
+import operator
 import time
 from typing import Callable, Iterable, NamedTuple
 
@@ -53,6 +60,20 @@ def bucket_size(n: int, base: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+#: [M] int32 seeds -> [M, 2] uint32 keys, ``jax.random.PRNGKey`` of each
+seed_keys = jax.vmap(jax.random.PRNGKey)
+
+
+def _seed(seed) -> int:
+    """``seed`` as the dispatch takes it: an int in ``[0, 2**31)``.  An
+    int32 outside that range would wrap, and its key would differ from
+    ``jax.random.PRNGKey(seed)``."""
+    s = operator.index(seed)
+    if not 0 <= s < 2**31:
+        raise ValueError(f"PRNG seed {s} is outside [0, 2**31)")
+    return s
 
 
 # ---------------------------------------------------------------------------
@@ -215,26 +236,31 @@ class BatchedSummaryEngine:
         C, bins, ck = self.num_classes, self.bins, self.coreset_k
         enc, uk = self.encoder_fn, self.use_kernel
         if self.method == "py":
-            def batched(feats, labels, valid, keys):
+            def family(feats, labels, valid, keys):
                 ld = batched_label_distribution(labels, valid, C)
                 return ld, ld
         elif self.method == "pxy":
-            def batched(feats, labels, valid, keys):
+            def family(feats, labels, valid, keys):
                 m, n = feats.shape[:2]
                 flat = feats.reshape(m, n, -1)
                 s = batched_pxy_histogram(flat, labels, valid, C, bins=bins,
                                           use_kernel=uk)
                 return s, batched_label_distribution(labels, valid, C)
         else:
-            def batched(feats, labels, valid, keys):
+            def family(feats, labels, valid, keys):
                 s = batched_encoder_summary(feats, labels, valid, enc, C, ck,
                                             keys, use_kernel=uk)
                 return s, batched_label_distribution(labels, valid, C)
+
+        def batched(feats, labels, valid, seeds):
+            return family(feats, labels, valid, seed_keys(seeds))
         return batched
 
     # ------------------------------------------------------------------
     def summarize(self, items: Iterable[tuple]) -> dict[int, SummaryResult]:
-        """items: iterable of ``(client_id, feats, labels, valid, key)``.
+        """items: iterable of ``(client_id, feats, labels, valid, seed)``,
+        ``seed`` an int in ``[0, 2**31)`` (the client's PRNG key is
+        ``jax.random.PRNGKey(seed)``, made inside the dispatch).
 
         Returns ``{client_id: SummaryResult}``.  Clients are grouped by
         (size bucket, feature shape); each group is dispatched in chunks of
@@ -242,13 +268,13 @@ class BatchedSummaryEngine:
         """
         groups: dict[tuple, list] = {}
         with obs.span("summary/load"):
-            for cid, feats, labels, valid, key in items:
+            for cid, feats, labels, valid, seed in items:
                 feats = np.asarray(feats, np.float32)
                 labels = np.asarray(labels, np.int32)
                 valid = np.asarray(valid, bool)
                 b = bucket_size(feats.shape[0])
                 groups.setdefault((b, feats.shape[1:]), []).append(
-                    (cid, feats, labels, valid, np.asarray(key)))
+                    (cid, feats, labels, valid, _seed(seed)))
 
         out: dict[int, SummaryResult] = {}
         for (b, fs), group in groups.items():
@@ -257,14 +283,17 @@ class BatchedSummaryEngine:
         return out
 
     def summarize_clients(self, client_ids, sizes, load_fn: Callable,
-                          key_fn: Callable) -> dict[int, SummaryResult]:
+                          seed_fn: Callable) -> dict[int, SummaryResult]:
         """Memory-bounded variant: group by size *before* loading any data,
         so at most ``max_batch`` clients' datasets are host-resident at a
         time (``summarize`` stages the whole stale set at once — fine for
         benchmarks, not for tens of thousands of stale clients).
 
         ``sizes[c]`` is client ``c``'s dataset size; ``load_fn(c)`` returns
-        ``(feats, labels, valid)``; ``key_fn(c)`` returns its PRNG key.
+        ``(feats, labels, valid)``; ``seed_fn(c)`` returns its PRNG seed, an
+        int in ``[0, 2**31)``: the dispatch makes ``jax.random.PRNGKey`` of
+        it bit for bit, so the same seed gives the same key and the same
+        coreset as the per-client path, and the load touches no device.
         Clients sharing a size bucket must share a feature shape (true for
         every ``FederatedDataset``).
         """
@@ -281,7 +310,7 @@ class BatchedSummaryEngine:
                         chunk.append((c, np.asarray(feats, np.float32),
                                       np.asarray(labels, np.int32),
                                       np.asarray(valid, bool),
-                                      np.asarray(key_fn(c))))
+                                      _seed(seed_fn(c))))
                 self._dispatch(chunk, b, chunk[0][1].shape[1:], out)
         return out
 
@@ -293,16 +322,15 @@ class BatchedSummaryEngine:
             feats = np.zeros((mp, b, *fs), np.float32)
             labels = np.zeros((mp, b), np.int32)
             valid = np.zeros((mp, b), bool)
-            key_shape = chunk[0][4].shape
-            keys = np.zeros((mp, *key_shape), chunk[0][4].dtype)
-            for i, (_cid, f, l, v, k) in enumerate(chunk):
+            seeds = np.zeros(mp, np.int32)
+            for i, (_cid, f, l, v, s) in enumerate(chunk):
                 n = f.shape[0]
                 feats[i, :n] = f
                 labels[i, :n] = l
                 valid[i, :n] = v
-                keys[i] = k
+                seeds[i] = s
             sp.annotate(filled=int(valid.sum()))
-        args = obs.device_put("summary/put", (feats, labels, valid, keys))
+        args = obs.device_put("summary/put", (feats, labels, valid, seeds))
 
         # AOT-compile per shape so compile time never lands in the timed
         # dispatch and the first chunk is not computed twice

@@ -399,9 +399,12 @@ class RoundContext:
         """-> (summaries {c: array} in ingest order, seconds {c: s}, wall).
 
         Pure compute — nothing is written to the registry here, so the
-        async server can hold results in its ingest queue.  PRNG keys are
-        a pure function of (round, client): the batched and per-client
-        paths stay bitwise-identical, and so do sync and async servers.
+        async server can hold results in its ingest queue.  Each client's
+        PRNG seed is a pure function of (round, client),
+        ``rnd * 100003 + c``: the batched engine makes
+        ``jax.random.PRNGKey(seed)`` inside its dispatch, the per-client
+        path makes the same key on the host, so the two stay
+        bitwise-identical, and so do sync and async servers.
         """
         summaries: dict[int, np.ndarray] = {}
         times: dict[int, float] = {}
@@ -419,7 +422,7 @@ class RoundContext:
             results = self.engine.summarize_clients(
                 stale, self.data.sizes,
                 lambda c: self.data.client_data(c, float(drift[c])),
-                lambda c: jax.random.PRNGKey(rnd * 100003 + c))
+                lambda c: rnd * 100003 + c)
             for c, res in results.items():
                 summaries[c] = res.summary
                 times[c] = res.seconds

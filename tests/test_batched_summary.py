@@ -1,6 +1,9 @@
 """Fleet-scale batched summary engine: numerical equivalence with the
-per-client ``timed_summary`` path (same bucket padding, same PRNG keys),
-dispatch accounting, kernel-backed batched paths, and registry bookkeeping."""
+per-client ``timed_summary`` path (same bucket padding, same PRNG seeds ⇒
+same keys), dispatch accounting, kernel-backed batched paths, and registry
+bookkeeping."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +14,7 @@ from repro.core import (
     BatchedSummaryEngine, RefreshPolicy, SummaryRegistry,
     batched_per_label_mean, batched_pxy_histogram, bucket_size,
 )
+from repro.core.batched_summary import seed_keys
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl.client import timed_summary
 from repro.models.cnn import CNNConfig, build_cnn, cnn_apply
@@ -31,7 +35,7 @@ def enc_fn():
 
 
 def _items(data, drift=0.0):
-    return [(c, *data.client_data(c, drift), jax.random.PRNGKey(1000 + c))
+    return [(c, *data.client_data(c, drift), 1000 + c)
             for c in range(data.spec.num_clients)]
 
 
@@ -134,7 +138,7 @@ def test_lazy_summarize_clients_matches_eager(data, enc_fn):
     res_b = lazy.summarize_clients(
         range(spec.num_clients), data.sizes,
         lambda c: data.client_data(c),
-        lambda c: jax.random.PRNGKey(1000 + c))
+        lambda c: 1000 + c)
     assert lazy.stats.dispatches == eager.stats.dispatches
     assert set(res_b) == set(res_a)
     for c in res_a:
@@ -149,6 +153,82 @@ def test_max_batch_chunks_dispatches():
     engine.summarize(_items(data))
     assert engine.stats.clients == 12
     assert engine.stats.dispatches >= 6     # ceil(group/2) per bucket
+
+
+@pytest.mark.parametrize("seed", [0, 5_000_011, 1_300 * 100_003 + 2_799,
+                                  2**31 - 1, -1, 2**31])
+def test_dispatch_keys_are_prng_key_of_seed(data, enc_fn, seed):
+    """The key the dispatch makes from a client's int32 seed is
+    ``jax.random.PRNGKey(seed)`` bit for bit over ``[0, 2**31)``, so the
+    encoder coreset is the per-client path's; a seed outside that range
+    raises instead of wrapping."""
+    c = int(np.argmax(data.sizes))          # the most samples: k < n
+    feats, labels, valid = data.client_data(c)
+    engine = BatchedSummaryEngine("encoder", data.spec.num_classes,
+                                  encoder_fn=enc_fn, coreset_k=16, bins=8)
+    if not 0 <= seed < 2**31:
+        with pytest.raises(ValueError, match="outside"):
+            engine.summarize([(c, feats, labels, valid, seed)])
+        with pytest.raises(ValueError, match="outside"):
+            engine.summarize_clients([c], data.sizes, data.client_data,
+                                     lambda _c: seed)
+        assert engine.stats.dispatches == 0
+        return
+    want = np.asarray(jax.random.PRNGKey(seed))
+    # the dispatch's key step, on the int32 array it is handed (padded 0s)
+    seeds = np.array([seed, 0], np.int32)
+    got = np.asarray(jax.jit(seed_keys)(seeds))
+    np.testing.assert_array_equal(got[0], want)
+    res = engine.summarize_clients([c], data.sizes, data.client_data,
+                                   lambda _c: seed)[c]
+    s, _, _ = timed_summary("encoder", feats, labels, valid,
+                            data.spec.num_classes, encoder_fn=enc_fn,
+                            coreset_k=16, bins=8,
+                            key=jax.random.PRNGKey(seed))
+    np.testing.assert_allclose(res.summary, s, atol=1e-5)
+    # the coreset depends on the key: another seed picks another one
+    other = engine.summarize([(c, feats, labels, valid, seed ^ 1)])[c]
+    assert np.max(np.abs(other.summary - s)) > 1e-3
+
+
+def _guard_load(monkeypatch):
+    """Disallow every host<->device transfer inside ``summary/load``."""
+    real = obs.span
+
+    @contextlib.contextmanager
+    def span(name, *args, **kw):
+        with real(name, *args, **kw) as sp:
+            if name != "summary/load":
+                yield sp
+                return
+            with jax.transfer_guard("disallow"):
+                yield sp
+
+    monkeypatch.setattr(obs, "span", span)
+
+
+def test_load_makes_no_device_round_trip(data, enc_fn, monkeypatch):
+    """The engine's load loop reads client data and integer seeds and
+    touches no device: no key is made on the device and read back per
+    client.  The guard is live: a device round trip in the load trips it."""
+    spec = data.spec
+    ids = list(range(spec.num_clients))
+    engine = BatchedSummaryEngine("encoder", spec.num_classes,
+                                  encoder_fn=enc_fn, coreset_k=16, bins=8)
+    engine.summarize_clients(ids, data.sizes, data.client_data,
+                             lambda c: 1000 + c)          # compiles, unguarded
+    _guard_load(monkeypatch)
+    results = engine.summarize_clients(ids, data.sizes, data.client_data,
+                                       lambda c: 1000 + c)
+    assert set(results) == set(ids)
+
+    def load_with_key(c):          # what a per-client PRNGKey readback does
+        np.asarray(jax.random.PRNGKey(c))
+        return data.client_data(c)
+
+    with pytest.raises(Exception, match="Disallowed"):
+        engine.summarize_clients(ids, data.sizes, load_with_key,
+                                 lambda c: 1000 + c)
 
 
 # ---------------------------------------------------------------------------
@@ -182,7 +262,7 @@ def test_profiled_rounds_count_copies_and_padding(data, max_batch, tmp_path):
             with obs.span("client_summaries", round=rnd):
                 engine.summarize_clients(
                     ids, data.sizes, data.client_data,
-                    lambda c: jax.random.PRNGKey(rnd * 1000 + c))
+                    lambda c: rnd * 1000 + c)
 
     events = _profiled(two_rounds, tmp_path)
     by_bucket: dict = {}
@@ -192,7 +272,7 @@ def test_profiled_rounds_count_copies_and_padding(data, max_batch, tmp_path):
     for b, cids in by_bucket.items():
         for lo in range(0, len(cids), max_batch):
             mp = bucket_size(len(cids[lo:lo + max_batch]), base=1)
-            want_bytes += mp * b * sample_bytes + mp * 2 * 4  # + uint32 keys
+            want_bytes += mp * b * sample_bytes + mp * 4  # + int32 seeds
             want_slots += mp * b
             want_puts += 1
 

@@ -146,7 +146,7 @@ def test_batched_bitwise_matches_per_client(diff_data, diff_engines, method,
         results = engine.summarize_clients(
             present, data.sizes,
             lambda c: data.client_data(c, drift),
-            lambda c: jax.random.PRNGKey(rnd * 1000 + c))
+            lambda c: rnd * 1000 + c)
         assert set(results) == set(int(c) for c in present)
         for c in present:
             feats, labels, valid = data.client_data(int(c), drift)

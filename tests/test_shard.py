@@ -240,7 +240,7 @@ def test_profiled_rounds_count_every_copy(clustering, tmp_path):
     assert put_bytes("drift_scan/put") == 2 * chunks * 2 * rows * c * 4
     sample_bytes = 8 * 8 * 1 * 4 + 4 + 1
     by_bucket = np.bincount([bucket_size(int(s)) for s in data.sizes])
-    want = sum(bucket_size(m, base=1) * (b * sample_bytes + 2 * 4)
+    want = sum(bucket_size(m, base=1) * (b * sample_bytes + 4)  # int32 seed
                for b, m in enumerate(by_bucket) if m)
     assert put_bytes("summary/put") == want
     x_bytes = n * c * 4                        # the P(y) summaries, float32
