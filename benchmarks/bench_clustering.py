@@ -111,8 +111,9 @@ def run_online(n: int = 10_000, dim: int = 64, k_clusters: int = 16,
                seed: int = 0) -> list:
     """Low-drift maintenance: per round, ``drift_frac`` of clients move to a
     new latent group; compare re-running full K-means every round (the
-    ``SummaryRegistry`` + ``kmeans`` baseline) against the online maintainer's
-    assign-only updates (DESIGN.md §5).  Both paths see identical data."""
+    registry's ``dense()`` + ``kmeans`` baseline) against the online
+    maintainer's assign-only updates (DESIGN.md §5).  Both paths see
+    identical data."""
     rs = np.random.RandomState(seed)
     centers = rs.normal(0, 4.0, (k_clusters, dim)).astype(np.float32)
     g = rs.randint(0, k_clusters, n)

@@ -151,7 +151,6 @@ def hooks_per_round(seed: int = 0) -> dict:
     cfg = api.RunConfig(
         rounds=6, clients_per_round=8, local_steps=1, summary="py",
         refresh_max_age=3, refresh_kl=0.05, eval_every=6, seed=seed,
-        registry=api.RegistryConfig(kind="streaming"),
         clustering=api.ClusteringConfig(kind="online", num_clusters=4),
         server=api.ServerConfig(kind="async", refresh="staleness",
                                 ingest_delay_rounds=1, snapshot_max_age=2,

@@ -7,7 +7,7 @@ exactly those pieces at fleet scale, plus the end-to-end kill + resume
 path on a real (small) federated run:
 
   * ``server_resume/ckpt_save/nN``  — ``save_state`` seconds for a
-    fleet-scale server checkpoint (streaming registry + online
+    fleet-scale server checkpoint (summary registry + online
     maintainer state, the dominant payload);
   * ``server_resume/ckpt_load/nN``  — ``load_state`` + restore into
     fresh runtime objects, the resume-side mirror;
@@ -36,9 +36,7 @@ from repro.checkpoint.server_state import (
 )
 from repro.core.scheduler import RefreshPolicy
 from repro.sim import synthetic_fleet
-from repro.stream import (
-    OnlineClusterMaintainer, OnlinePolicy, StreamingSummaryRegistry,
-)
+from repro.stream import OnlineClusterMaintainer, OnlinePolicy, SummaryRegistry
 
 
 def _server_state(n: int, seed: int, num_classes: int = 10, dim: int = 8,
@@ -47,7 +45,7 @@ def _server_state(n: int, seed: int, num_classes: int = 10, dim: int = 8,
     arrays that dominate checkpoint bytes."""
     fleet = synthetic_fleet(n, num_classes, dim, seed=seed)
     policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
-    reg = StreamingSummaryRegistry(n, policy)
+    reg = SummaryRegistry(n, policy)
     reg.update_batch(np.arange(n), 0, fleet.summaries, fleet.label_dists)
     m = OnlineClusterMaintainer(k, OnlinePolicy(reseed_every=10 ** 9))
     m.refresh(reg.dense(), np.arange(n), jax.random.PRNGKey(seed),
@@ -66,7 +64,7 @@ def bench_checkpoint(n: int, seed: int = 0, repeats: int = 3) -> dict:
             t0 = time.perf_counter()
             save_state(base, tree)
             saves.append(time.perf_counter() - t0)
-            fresh_reg = StreamingSummaryRegistry(n, policy)
+            fresh_reg = SummaryRegistry(n, policy)
             fresh_m = OnlineClusterMaintainer(
                 m.k, OnlinePolicy(reseed_every=10 ** 9))
             t0 = time.perf_counter()
@@ -108,7 +106,6 @@ def bench_resume_run(seed: int = 0, rounds: int = 3) -> dict:
     cfg = api.RunConfig(
         rounds=rounds, clients_per_round=4, local_steps=1, summary="py",
         eval_every=rounds, seed=seed,
-        registry=api.RegistryConfig(kind="streaming"),
         clustering=api.ClusteringConfig(num_clusters=3, recluster_every=2))
     t0 = time.perf_counter()
     api.run(data, cfg)

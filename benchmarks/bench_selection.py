@@ -1,12 +1,11 @@
 """End-to-end selection quality (paper §2: HACCS's 18–38 % training-time
 reduction mechanism): simulated time-to-accuracy of cluster-aware selection
 vs random / fastest-only selection under system heterogeneity — plus the
-scenario sweep (DESIGN.md §6): every named fleet preset run through the
-registry x clustering support matrix with per-round coverage/overhead/
-dropout metrics.
+scenario sweep (DESIGN.md §6): every named fleet preset run under each
+clustering backend with per-round coverage/overhead/dropout metrics.
 
 CSV: strategy,final_acc,sim_time_to_target,refreshes
-     scenario/<preset>/<registry>-<clustering>,0,final_acc=..;kl_cov=..;...
+     scenario/<preset>/<clustering>,0,final_acc=..;kl_cov=..;...
 """
 from __future__ import annotations
 
@@ -53,14 +52,14 @@ def run(rounds: int = 16, clients: int = 60, target_acc: float = 0.85,
     return rows
 
 
-SCENARIO_COMBOS = (("dict", "kmeans"), ("dict", "minibatch"),
-                   ("streaming", "kmeans"), ("streaming", "online"))
+SCENARIO_CLUSTERINGS = ("kmeans", "minibatch", "online")
 
 
 def run_scenarios(rounds: int = 8, clients: int = 48, seed: int = 0,
-                  combos=SCENARIO_COMBOS, presets=PRESET_NAMES) -> list:
-    """Every scenario preset through the registry x clustering support
-    matrix; per-round metrics aggregated into one record per cell."""
+                  clusterings=SCENARIO_CLUSTERINGS,
+                  presets=PRESET_NAMES) -> list:
+    """Every scenario preset under each clustering backend; per-round
+    metrics aggregated into one record per cell."""
     rows = []
     for preset in presets:
         alpha = DATA_HINTS[preset].get("alpha", 0.5)
@@ -68,22 +67,20 @@ def run_scenarios(rounds: int = 8, clients: int = 48, seed: int = 0,
                                            side=10, avg_samples=48,
                                            num_styles=4, alpha=alpha),
                                 seed=seed)
-        for registry, clustering in combos:
+        for clustering in clusterings:
             scenario = make_scenario(preset, clients, seed=seed)
             cfg = api.RunConfig(
                 rounds=rounds, clients_per_round=8, local_steps=4,
                 summary="py", refresh_kl=0.05,
                 eval_every=max(rounds - 1, 1), seed=seed,
-                registry=api.RegistryConfig(kind=registry),
                 clustering=api.ClusteringConfig(kind=clustering,
                                                 num_clusters=6,
                                                 recluster_every=4))
             h = api.run(data, cfg, scenario=scenario)
             kl = np.asarray(h["kl_coverage"], np.float64)
             rows.append({
-                "name": f"scenario/{preset}/{registry}-{clustering}",
+                "name": f"scenario/{preset}/{clustering}",
                 "preset": preset,
-                "registry": registry,
                 "clustering": clustering,
                 "final_acc": h["final_acc"],
                 "kl_coverage": (float(np.nanmean(kl))
@@ -112,10 +109,9 @@ def main(fast: bool = True):
         emit("selection/time_reduction_vs_random",
              text=f"{red * 100:.1f}%")
 
-    fast_combos = (("dict", "kmeans"), ("streaming", "online"))
     sc_rows = run_scenarios(
         rounds=4 if fast else 12, clients=32 if fast else 96,
-        combos=fast_combos if fast else SCENARIO_COMBOS)
+        clusterings=("kmeans", "online") if fast else SCENARIO_CLUSTERINGS)
     for r in sc_rows:
         emit(r["name"], final_acc=f"{r['final_acc']:.3f}",
              kl_cov=f"{r['kl_coverage']:.4f}", dropped=r["dropped"],

@@ -5,7 +5,7 @@ selection cost at fleet scale; DESIGN.md §8's claim is that an async
 server takes that overhead *off the round-critical path*.  This bench
 measures exactly that, with the real server components
 (``repro.server``: ingest queue, snapshot store, bounded-staleness
-refresher) over the real streaming registry and online cluster
+refresher) over the real summary registry and online cluster
 maintainer, headless (no client training — server-side work only):
 
   * ``server/sync/nN``  — per-round critical-path seconds when every
@@ -41,8 +41,7 @@ from repro.server import (
     capture,
 )
 from repro.sim import drift_fleet, synthetic_fleet
-from repro.stream import OnlineClusterMaintainer, OnlinePolicy, \
-    StreamingSummaryRegistry
+from repro.stream import OnlineClusterMaintainer, OnlinePolicy, SummaryRegistry
 
 
 class _HeadlessCtx:
@@ -91,7 +90,7 @@ def run_server(n: int, mode: str, rounds: int = 6, num_classes: int = 10,
     assert mode in ("sync", "async")
     fleet = synthetic_fleet(n, num_classes, dim, seed=seed)
     policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
-    registry = StreamingSummaryRegistry(n, policy)
+    registry = SummaryRegistry(n, policy)
     registry.update_batch(np.arange(n), 0, fleet.summaries,
                           fleet.label_dists)
     ctx = _HeadlessCtx(registry, k, seed)

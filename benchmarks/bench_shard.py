@@ -2,10 +2,9 @@
 
 Measures the two scale-out claims of DESIGN.md §7 on this host:
 
-  * ``sharded/scan/*`` — the chunked device-mesh drift scan
-    (``ShardedSummaryRegistry``) vs the single-shot numpy scan of the
-    streaming baseline, including the N=1M row arena that must stream
-    through fixed-size chunks under the CI memory budget;
+  * ``sharded/scan/*`` — the registry's chunked device-mesh drift scan
+    (``repro.stream.SummaryRegistry``), including the N=1M row arena that
+    must stream through fixed-size chunks under the CI memory budget;
   * ``sharded/pipeline/*`` — one full server round (drift scan →
     O(drifted) scatter → hierarchical shard-local maintenance → weighted
     global merge) with per-stage seconds.
@@ -24,9 +23,9 @@ import numpy as np
 
 from benchmarks._record import emit
 from repro.core.scheduler import RefreshPolicy
-from repro.shard import HierarchicalClusterMaintainer, ShardedSummaryRegistry
+from repro.shard import HierarchicalClusterMaintainer
 from repro.sim import drift_fleet, synthetic_fleet
-from repro.stream import StreamingSummaryRegistry
+from repro.stream import SummaryRegistry
 
 
 def _peak_mb() -> float:
@@ -39,30 +38,23 @@ def _peak_mb() -> float:
 def run_scan(n: int, num_classes: int = 10, dim: int = 8,
              chunk_rows: int = 131072, drift_frac: float = 0.01,
              seed: int = 0) -> dict:
-    """One round of refresh decisions at fleet scale: streaming (numpy,
-    whole arena at once) vs sharded (device mesh, fixed-size chunks)."""
+    """One round of refresh decisions at fleet scale (device mesh,
+    fixed-size chunks)."""
     fleet = synthetic_fleet(n, num_classes, dim, seed=seed)
     policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
-    stream = StreamingSummaryRegistry(n, policy)
-    shard = ShardedSummaryRegistry(n, policy, chunk_rows=chunk_rows)
-    for reg in (stream, shard):
-        reg.update_batch(np.arange(n), 0, fleet.summaries, fleet.label_dists)
+    shard = SummaryRegistry(n, policy, chunk_rows=chunk_rows)
+    shard.update_batch(np.arange(n), 0, fleet.summaries, fleet.label_dists)
     fresh, _ = drift_fleet(fleet.label_dists, drift_frac, seed=seed + 1)
-
-    t0 = time.perf_counter()
-    want = stream.stale_clients(1, fresh)
-    numpy_s = time.perf_counter() - t0
 
     shard.stale_clients(1, fresh)            # warm: compile the chunk scan
     chunks0 = shard.scan_chunks
     t0 = time.perf_counter()
     got = shard.stale_clients(1, fresh)
     scan_s = time.perf_counter() - t0
-    assert np.array_equal(want, got), "sharded decisions diverged"
     return {"n": n, "n_shards": shard.n_shards,
             "chunk_rows": shard.chunk_rows,
             "chunks": shard.scan_chunks - chunks0,
-            "stale": int(want.size), "numpy_s": numpy_s, "scan_s": scan_s,
+            "stale": int(got.size), "scan_s": scan_s,
             "peak_mb": _peak_mb()}
 
 
@@ -73,7 +65,7 @@ def run_pipeline(n: int, num_classes: int = 10, dim: int = 16, k: int = 8,
     scatter → shard-local online maintenance → weighted global merge."""
     fleet = synthetic_fleet(n, num_classes, dim, seed=seed)
     policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
-    reg = ShardedSummaryRegistry(n, policy, chunk_rows=chunk_rows)
+    reg = SummaryRegistry(n, policy, chunk_rows=chunk_rows)
     reg.update_batch(np.arange(n), 0, fleet.summaries, fleet.label_dists)
     hm = HierarchicalClusterMaintainer(k, n_shards=reg.n_shards,
                                        local_k=local_k)
@@ -108,7 +100,7 @@ def main(fast: bool = True):
         rows.append(r)
         emit(f"sharded/scan/n{n}", us=r["scan_s"] * 1e6,
              n_shards=r["n_shards"], scan_s=f"{r['scan_s']:.4f}",
-             numpy_s=f"{r['numpy_s']:.4f}", chunks=r["chunks"],
+             chunks=r["chunks"],
              chunk_rows=r["chunk_rows"], stale=r["stale"],
              peak_mb=f"{r['peak_mb']:.0f}")
 

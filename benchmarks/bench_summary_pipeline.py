@@ -8,9 +8,9 @@ for real on this host):
                stacked into padded [M, N_bucket, ...] buckets, ONE jitted
                vmap dispatch per bucket chunk instead of one per client.
   iteration 3: server-side registry scan (DESIGN.md §5) — per-client
-               needs_refresh python loop vs one batched sym-KL over [N, C]
-               vs the streaming registry (dense matrices, O(drifted)
-               scatter) at 10k-100k simulated clients.
+               needs_refresh python loop vs the registry's chunked scan
+               (dense matrices, O(drifted) scatter) at 10k-100k simulated
+               clients.
 
 CSV: pipeline/<...>,us_per_call,derived
 """
@@ -23,8 +23,8 @@ import numpy as np
 import jax
 
 from benchmarks._record import emit
-from repro.core import BatchedSummaryEngine, RefreshPolicy, SummaryRegistry
-from repro.stream import StreamingSummaryRegistry
+from repro.core import BatchedSummaryEngine, RefreshPolicy
+from repro.stream import SummaryRegistry
 from repro.data.synthetic import DatasetSpec, FederatedDataset, small_spec
 from repro.fl.client import timed_summary
 from repro.models.cnn import CNNConfig, build_cnn, cnn_apply
@@ -106,18 +106,15 @@ def run_fleet(num_clients: int = 512, methods=("py", "encoder", "pxy"),
 def run_registry(n: int = 20_000, num_classes: int = 62, dim: int = 64,
                  drift_frac: float = 0.01, seed: int = 0) -> list:
     """Iteration 3: one server round of refresh decisions + state absorption
-    at fleet scale — the python-loop scan vs the vectorized dict registry vs
-    the streaming registry's batched scan + O(drifted) scatter."""
+    at fleet scale — the per-client python-loop predicate vs the registry's
+    chunked scan + O(drifted) scatter."""
     rs = np.random.RandomState(seed)
     policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
     dists = rs.dirichlet([0.5] * num_classes, n).astype(np.float32)
     summaries = rs.rand(n, dim).astype(np.float32)
 
-    base = SummaryRegistry(n, policy)
-    stream = StreamingSummaryRegistry(n, policy)
-    for c in range(n):
-        base.update(c, 0, summaries[c], dists[c])
-    stream.update_batch(np.arange(n), 0, summaries, dists)
+    reg = SummaryRegistry(n, policy)
+    reg.update_batch(np.arange(n), 0, summaries, dists)
 
     # low drift: a few % of clients move, the rest stay put
     fresh = dists.copy()
@@ -127,22 +124,18 @@ def run_registry(n: int = 20_000, num_classes: int = 62, dim: int = 64,
 
     t0 = time.perf_counter()
     loop_stale = [c for c in range(n)
-                  if base.needs_refresh(c, 1, fresh[c])]
+                  if reg.needs_refresh(c, 1, fresh[c])]
     loop_s = time.perf_counter() - t0
+    reg.stale_clients(1, fresh)              # warm: compile the chunk scan
     t0 = time.perf_counter()
-    vec_stale = base.stale_clients(1, fresh)
-    vec_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    stream_stale = stream.stale_clients(1, fresh)
-    stream.update_batch(stream_stale, 1,
-                        summaries[stream_stale], fresh[stream_stale])
-    _ = stream.matrix()                      # zero-copy clustering handoff
-    stream_s = time.perf_counter() - t0
-    assert loop_stale == vec_stale == stream_stale.tolist()
+    stale = reg.stale_clients(1, fresh)
+    reg.update_batch(stale, 1, summaries[stale], fresh[stale])
+    _ = reg.dense()                          # zero-copy clustering handoff
+    scan_s = time.perf_counter() - t0
+    assert loop_stale == stale.tolist()
     return [{
         "name": f"pipeline/registry/n{n}", "n": n, "num_classes": num_classes,
-        "stale": len(loop_stale), "loop_s": loop_s, "vectorized_s": vec_s,
-        "streaming_s": stream_s,
+        "stale": len(loop_stale), "loop_s": loop_s, "scan_s": scan_s,
     }]
 
 
@@ -183,10 +176,8 @@ def main(fast: bool = True):
     for r in reg:
         emit(f"{r['name']}/loop", us=r["loop_s"] * 1e6, n=r["n"],
              stale=r["stale"])
-        emit(f"{r['name']}/vectorized", us=r["vectorized_s"] * 1e6,
-             text=f"{r['loop_s'] / max(r['vectorized_s'], 1e-9):.1f}x_vs_loop")
-        emit(f"{r['name']}/streaming", us=r["streaming_s"] * 1e6,
-             text=f"{r['loop_s'] / max(r['streaming_s'], 1e-9):.1f}x_vs_loop "
+        emit(f"{r['name']}/scan", us=r["scan_s"] * 1e6,
+             text=f"{r['loop_s'] / max(r['scan_s'], 1e-9):.1f}x_vs_loop "
                   f"(scan + O(drifted) scatter + zero-copy matrix)")
     return rows + fleet + reg
 

@@ -6,16 +6,17 @@
 One chip.  ``repro.api.run`` serves the FEMNIST-shaped fleet at its
 published size (2,800 clients, 62 classes, 28x28x1 images, lognormal sizes
 up to 512) for a few rounds: encoder summaries through the batched engine
-with the Pallas kernels, the sharded registry on one shard, online
+with the Pallas kernels, the registry's drift scan on one shard, online
 clustering, the async server with the staleness refresher and the Poisson
 check-in front end, HACCS selection and a CNN payload.  Round 0 summarizes
 and clusters the whole fleet.  Then one summary dispatch with the kernels
 is compared with the jnp reference on the same clients.
 
-Four chips.  Only the sharded path runs: ``ShardedSummaryRegistry`` on a
-4-device ``fleet_mesh`` over a million-client arena at C = 62 in
+Four chips.  Only the sharded path runs: ``SummaryRegistry``'s drift scan
+on a 4-device ``fleet_mesh`` over a million-client arena at C = 62 in
 131072-row chunks, and ``HierarchicalClusterMaintainer`` with 4 shards.
-Its drift decisions must equal ``StreamingSummaryRegistry``'s.
+Its drift decisions must equal the exact host math's (``batch_sym_kl``
+over the same arenas).
 
 The script exits non-zero, and prints no result line, when JAX finds no
 TPU, when a phase raises, when the summary dispatch holds no Pallas kernel
@@ -94,11 +95,10 @@ def run_config(rounds: int, seed: int):
     import repro.api as api
     return api.RunConfig(
         rounds=rounds, model=api.Model.CNN, summary=api.Summary.ENCODER,
-        summary_engine=api.SummaryEngine.BATCHED,
         # label drift from round 2 makes later rounds re-summarize and
         # re-assign the drifted clients
         drift_start=2, drift_per_round=0.25, seed=seed,
-        registry=api.RegistryConfig(kind=api.Registry.SHARDED, n_shards=1),
+        registry=api.RegistryConfig(n_shards=1),
         clustering=api.ClusteringConfig(kind=api.Clustering.ONLINE,
                                         num_clusters=NUM_CLUSTERS),
         server=api.ServerConfig(
@@ -211,16 +211,14 @@ def four_chips(clock: CompileClock, seed: int) -> None:
     import jax
     import numpy as np
 
-    from repro.core.scheduler import RefreshPolicy
+    from repro.core.scheduler import RefreshPolicy, batch_sym_kl
     from repro.kernels.ops import on_tpu
-    from repro.shard import (
-        HierarchicalClusterMaintainer, ShardedSummaryRegistry,
-    )
+    from repro.shard import HierarchicalClusterMaintainer
     from repro.sim import drift_fleet, synthetic_fleet
-    from repro.stream import OnlinePolicy, StreamingSummaryRegistry
+    from repro.stream import OnlinePolicy, SummaryRegistry
     from repro.utils.sharding import fleet_mesh
 
-    n, c, chunk = 1_000_000, 62, 131072
+    n, c, chunk, kl = 1_000_000, 62, 131072, 0.05
     mesh = fleet_mesh(4)
     print(f"fleet mesh: {mesh.devices.size} devices "
           f"({', '.join(str(d) for d in mesh.devices.flat)})", flush=True)
@@ -229,16 +227,13 @@ def four_chips(clock: CompileClock, seed: int) -> None:
 
     def setup():
         fleet = synthetic_fleet(n, c, seed=seed)
-        policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
-        shard = ShardedSummaryRegistry(n, policy, mesh=mesh,
-                                       chunk_rows=chunk)
-        stream = StreamingSummaryRegistry(n, policy)
-        for reg in (shard, stream):
-            reg.update_batch(np.arange(n), 0, fleet.summaries,
-                             fleet.label_dists)
-        return fleet, shard, stream
+        policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=kl)
+        shard = SummaryRegistry(n, policy, mesh=mesh, chunk_rows=chunk)
+        shard.update_batch(np.arange(n), 0, fleet.summaries,
+                           fleet.label_dists)
+        return fleet, shard
 
-    fleet, shard, stream = phase(clock, "arena", setup)
+    fleet, shard = phase(clock, "arena", setup)
     hm = HierarchicalClusterMaintainer(
         NUM_CLUSTERS, n_shards=4, local_k=16,
         policy=OnlinePolicy(use_kernel=on_tpu()))
@@ -249,16 +244,17 @@ def four_chips(clock: CompileClock, seed: int) -> None:
         ld = fleet.label_dists
         for rnd in (1, 2):
             fresh, _ = drift_fleet(ld, 0.01, seed=seed + rnd)
+            # every row holds a summary and none ages out: the exact
+            # stale set is the rows whose host sym-KL passes the threshold
+            want = np.flatnonzero(batch_sym_kl(shard.label_dists, fresh) > kl)
             got = shard.stale_clients(rnd, fresh)
-            want = stream.stale_clients(rnd, fresh)
             same = np.array_equal(got, want)
-            print(f"  round {rnd}: stale sharded {got.size}, streaming "
+            print(f"  round {rnd}: stale sharded {got.size}, exact "
                   f"{want.size}, identical {same}", flush=True)
             if not same:
                 fail(f"round {rnd}: sharded drift decisions differ from "
-                     "the streaming registry's")
-            for reg in (shard, stream):
-                reg.update_batch(got, rnd, fleet.summaries[got], fresh[got])
+                     "the exact host math's")
+            shard.update_batch(got, rnd, fleet.summaries[got], fresh[got])
             out = hm.refresh(shard.dense(), got,
                              jax.random.PRNGKey(rnd))
             print(f"  round {rnd}: hierarchical refresh over {got.size} "

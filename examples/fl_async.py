@@ -28,7 +28,6 @@ def run_one(server: str, data, sc_config: dict, args) -> dict:
         local_steps=args.local_steps, summary=args.summary,
         refresh_kl=0.05, eval_every=max(args.rounds // 4, 1),
         seed=args.seed,
-        registry=api.RegistryConfig(kind=args.registry),
         clustering=api.ClusteringConfig(kind=args.clustering,
                                         num_clusters=6, recluster_every=4),
         server=api.ServerConfig(
@@ -51,8 +50,6 @@ def main():
     ap.add_argument("--local-steps", type=int, default=4)
     ap.add_argument("--summary", default="py",
                     choices=["py", "pxy", "encoder"])
-    ap.add_argument("--registry", default="streaming",
-                    choices=["dict", "streaming", "sharded"])
     ap.add_argument("--clustering", default="kmeans",
                     choices=["kmeans", "minibatch", "online",
                              "hierarchical"])
@@ -77,9 +74,8 @@ def main():
 
     runs = {s: run_one(s, data, sc_config, args) for s in ("sync", "async")}
 
-    print(f"\n=== {args.preset}  ({args.registry} registry, "
-          f"{args.clustering} clustering, delay={args.delay}r, "
-          f"max_age={args.max_age}r)")
+    print(f"\n=== {args.preset}  ({args.clustering} clustering, "
+          f"delay={args.delay}r, max_age={args.max_age}r)")
     print("          ---- overhead on critical path (ms) ----")
     print("  rnd      sync     async   snap_age  snap_ver   acc(s/a)")
     step = max(args.rounds // 8, 1)

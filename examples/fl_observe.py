@@ -61,7 +61,6 @@ def main():
         rounds=args.rounds, clients_per_round=8, local_steps=1,
         summary="py", refresh_max_age=3, refresh_kl=0.05,
         eval_every=max(args.rounds // 2, 1), seed=args.seed,
-        registry=api.RegistryConfig(kind="streaming"),
         clustering=api.ClusteringConfig(kind="online", num_clusters=4),
         server=api.ServerConfig(kind="async", refresh="staleness",
                                 ingest_delay_rounds=1,

@@ -42,8 +42,6 @@ def main():
     ap.add_argument("--preset", default="mobile-churn",
                     choices=list(PRESET_NAMES))
     ap.add_argument("--server", default="sync", choices=["sync", "async"])
-    ap.add_argument("--registry", default="streaming",
-                    choices=["dict", "streaming", "sharded"])
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--clients", type=int, default=64)
     ap.add_argument("--crash-round", type=int, default=None,
@@ -63,15 +61,13 @@ def main():
     cfg = api.RunConfig(
         rounds=args.rounds, clients_per_round=8, local_steps=1,
         summary="py", eval_every=max(args.rounds // 3, 1), seed=args.seed,
-        registry=api.RegistryConfig(kind=args.registry),
         clustering=api.ClusteringConfig(num_clusters=4, recluster_every=2),
         server=api.ServerConfig(kind=args.server))
     crash_round = (args.rounds - 1 if args.crash_round is None
                    else args.crash_round)
     crash = (crash_round, Stage[args.crash_stage])
 
-    print(f"=== {args.server} server, {args.registry} registry, "
-          f"{args.preset}, {args.rounds} rounds")
+    print(f"=== {args.server} server, {args.preset}, {args.rounds} rounds")
     print("--- run 1: uninterrupted (reference)")
     h0 = api.run(data, cfg, scenario=Scenario.from_config(sc))
 

@@ -7,10 +7,9 @@
 Each preset models a different system-heterogeneity regime (churn,
 diurnal availability, stragglers with round deadlines, label drift); the
 round loop reports how selection coverage, summary overhead, and dropped
-clients respond.  ``--registry``/``--clustering``/``--server`` pick a
-cell of the support matrix (dict/streaming/sharded x kmeans/minibatch/
-online/hierarchical x sync/async — ``examples/fl_async.py`` compares the
-two servers side by side).
+clients respond.  ``--clustering``/``--server`` pick a cell of the
+support matrix (kmeans/minibatch/online/dbscan/hierarchical x sync/async —
+``examples/fl_async.py`` compares the two servers side by side).
 """
 import argparse
 
@@ -33,14 +32,13 @@ def run_preset(preset: str, args) -> dict:
         local_steps=args.local_steps, summary=args.summary,
         coreset_k=32, refresh_kl=0.05,
         eval_every=max(args.rounds // 4, 1), seed=args.seed,
-        registry=api.RegistryConfig(kind=args.registry),
         clustering=api.ClusteringConfig(kind=args.clustering,
                                         num_clusters=6, recluster_every=4),
         server=api.ServerConfig(kind=args.server))
     h = api.run(data, cfg, scenario=scenario)
 
-    print(f"\n=== {preset}  ({args.registry} registry, "
-          f"{args.clustering} clustering, {args.server} server)")
+    print(f"\n=== {preset}  ({args.clustering} clustering, "
+          f"{args.server} server)")
     print("  rnd   acc  sim_time  active  join/dep  dropped  kl_cov")
     step = max(args.rounds // 8, 1)
     for r in range(0, args.rounds, step):
@@ -69,8 +67,6 @@ def main():
     ap.add_argument("--local-steps", type=int, default=4)
     ap.add_argument("--summary", default="py",
                     choices=["py", "pxy", "encoder", "none"])
-    ap.add_argument("--registry", default="streaming",
-                    choices=["dict", "streaming", "sharded"])
     ap.add_argument("--clustering", default="kmeans",
                     choices=["kmeans", "minibatch", "online", "dbscan",
                              "hierarchical"])

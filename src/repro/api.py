@@ -1,12 +1,12 @@
 """Typed front door for the reproduction (DESIGN.md §12).
 
 ``repro.fl.FLConfig`` grew organically: ~40 flat fields, every backend
-chosen by a raw string, cross-field contracts (hierarchical clustering
-needs the sharded registry; the check-in front end needs the async
-server) enforced only deep inside ``RoundContext`` — or not at all.
+chosen by a raw string, cross-field contracts (the check-in front end
+needs the async server) enforced only deep inside ``RoundContext`` — or
+not at all.
 This module is the redesigned entry surface:
 
-  * enum-backed knobs (``Registry.SHARDED``, ``Server.ASYNC``, ...)
+  * enum-backed knobs (``Clustering.ONLINE``, ``Server.ASYNC``, ...)
     whose *values* are exactly the legacy strings, so configs remain
     greppable and serialize to the same tokens;
   * small composable sub-configs (``RegistryConfig``,
@@ -28,7 +28,7 @@ The one entry point::
 
     cfg = api.RunConfig(
         rounds=20, summary=api.Summary.PY,
-        registry=api.RegistryConfig(kind=api.Registry.SHARDED, n_shards=4),
+        registry=api.RegistryConfig(n_shards=4),
         clustering=api.ClusteringConfig(kind=api.Clustering.HIERARCHICAL),
         server=api.ServerConfig(kind=api.Server.ASYNC,
                                 refresh=api.Refresh.STALENESS),
@@ -44,7 +44,7 @@ from typing import Any, Mapping
 from repro.fl.rounds import FLConfig
 
 __all__ = [
-    "Summary", "SummaryEngine", "Model", "Registry", "Clustering",
+    "Summary", "Model", "Clustering",
     "Server", "Refresh", "Frontend",
     "RegistryConfig", "ClusteringConfig", "FrontendConfig", "ServerConfig",
     "PolicyConfig", "DurabilityConfig", "RunConfig", "run",
@@ -63,20 +63,9 @@ class Summary(str, enum.Enum):
     NONE = "none"
 
 
-class SummaryEngine(str, enum.Enum):
-    BATCHED = "batched"
-    PERCLIENT = "perclient"
-
-
 class Model(str, enum.Enum):
     MLP = "mlp"
     CNN = "cnn"
-
-
-class Registry(str, enum.Enum):
-    DICT = "dict"
-    STREAMING = "streaming"
-    SHARDED = "sharded"
 
 
 class Clustering(str, enum.Enum):
@@ -123,13 +112,11 @@ def _set(obj, field: str, value) -> None:
 
 @dataclasses.dataclass(frozen=True)
 class RegistryConfig:
-    """Where summaries live and how drift is scanned (DESIGN.md §5, §7)."""
-    kind: Registry = Registry.DICT
-    n_shards: int = 0               # sharded: 0 = one shard per device
-    chunk_rows: int = 131072        # sharded: scan chunk (device-memory cap)
+    """How the registry's drift scan is laid out (DESIGN.md §5, §7)."""
+    n_shards: int = 0               # 0 = one shard per device
+    chunk_rows: int = 131072        # scan chunk (device-memory cap)
 
     def __post_init__(self):
-        _set(self, "kind", _coerce(Registry, self.kind, "registry"))
         if self.n_shards < 0:
             raise ValueError("n_shards must be >= 0")
         if self.chunk_rows < 1:
@@ -240,6 +227,9 @@ class DurabilityConfig:
 # ---------------------------------------------------------------------------
 # the run config
 
+_SUBCONFIGS = (("registry", RegistryConfig), ("clustering", ClusteringConfig),
+               ("server", ServerConfig), ("policy", PolicyConfig))
+
 
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
@@ -255,7 +245,6 @@ class RunConfig:
     hidden: int = 64
     # --- paper technique ---
     summary: Summary = Summary.ENCODER
-    summary_engine: SummaryEngine = SummaryEngine.BATCHED
     coreset_k: int = 64
     encoder_dim: int = 32
     bins: int = 8
@@ -278,12 +267,7 @@ class RunConfig:
     def __post_init__(self):
         _set(self, "model", _coerce(Model, self.model, "model"))
         _set(self, "summary", _coerce(Summary, self.summary, "summary"))
-        _set(self, "summary_engine",
-             _coerce(SummaryEngine, self.summary_engine, "summary_engine"))
-        for field, cls in (("registry", RegistryConfig),
-                           ("clustering", ClusteringConfig),
-                           ("server", ServerConfig),
-                           ("policy", PolicyConfig)):
+        for field, cls in _SUBCONFIGS:
             v = getattr(self, field)
             if isinstance(v, Mapping):
                 _set(self, field, cls(**v))
@@ -297,12 +281,6 @@ class RunConfig:
         if self.clients_per_round < 1:
             raise ValueError("clients_per_round must be >= 1")
         # --- cross-field contracts the flat config silently ignored ---
-        if (self.clustering.kind is Clustering.HIERARCHICAL
-                and self.registry.kind is not Registry.SHARDED):
-            raise ValueError(
-                "clustering=hierarchical requires registry=sharded — the "
-                "two-level merge consumes shard-local centroids "
-                "(DESIGN.md §7)")
         if (self.server.frontend.kind is not Frontend.NONE
                 and self.server.kind is not Server.ASYNC):
             raise ValueError(
@@ -327,9 +305,7 @@ class RunConfig:
             local_steps=self.local_steps, batch_size=self.batch_size,
             lr=self.lr, fedprox_mu=self.fedprox_mu, model=self.model.value,
             hidden=self.hidden, summary=self.summary.value,
-            selection=self.policy.name,
-            summary_engine=self.summary_engine.value,
-            registry=r.kind.value, clustering=c.kind.value,
+            selection=self.policy.name, clustering=c.kind.value,
             online_inertia_ratio=c.online_inertia_ratio,
             online_reseed_every=c.online_reseed_every,
             n_shards=r.n_shards, shard_chunk_rows=r.chunk_rows,
@@ -363,10 +339,10 @@ class RunConfig:
             local_steps=cfg.local_steps, batch_size=cfg.batch_size,
             lr=cfg.lr, fedprox_mu=cfg.fedprox_mu, model=cfg.model,
             hidden=cfg.hidden, summary=cfg.summary,
-            summary_engine=cfg.summary_engine, coreset_k=cfg.coreset_k,
+            coreset_k=cfg.coreset_k,
             encoder_dim=cfg.encoder_dim, bins=cfg.bins,
             refresh_max_age=cfg.refresh_max_age, refresh_kl=cfg.refresh_kl,
-            registry=RegistryConfig(kind=cfg.registry, n_shards=cfg.n_shards,
+            registry=RegistryConfig(n_shards=cfg.n_shards,
                                     chunk_rows=cfg.shard_chunk_rows),
             clustering=ClusteringConfig(
                 kind=cfg.clustering, num_clusters=cfg.num_clusters,
@@ -419,6 +395,10 @@ class RunConfig:
     def from_dict(cls, d: Mapping[str, Any]) -> "RunConfig":
         kw = dict(d)
         unknown = set(kw) - {f.name for f in dataclasses.fields(cls)}
+        for field, sub in _SUBCONFIGS:
+            if isinstance(kw.get(field), Mapping):
+                unknown |= {f"{field}.{k}" for k in set(kw[field])
+                            - {f.name for f in dataclasses.fields(sub)}}
         if unknown:
             raise ValueError(f"unknown RunConfig fields: {sorted(unknown)}")
         # __post_init__ coerces nested mappings into the sub-configs

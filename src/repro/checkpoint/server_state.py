@@ -1,11 +1,11 @@
 """(De)serializers for the server's durable state (DESIGN.md §9).
 
 Everything the round loop cannot rebuild deterministically from the
-config is captured here: registry contents + write-version counters
-(all three backends), cluster-maintainer state, the driver RNG, model
-params, the history trace, and — for the async server — the
-``(round, stage, seq)`` event queue, in-flight ingest batches, the
-snapshot store and the refresher's drift-mass bookkeeping.  Each
+config is captured here: registry contents + write-version counters,
+cluster-maintainer state, the driver RNG, model params, the history
+trace, and — for the async server — the ``(round, stage, seq)`` event
+queue, in-flight ingest batches, the snapshot store and the refresher's
+drift-mass bookkeeping.  Each
 ``*_state`` function returns a plain nested dict of arrays/scalars fit
 for ``checkpoint.save_state``; each ``restore_*`` is its exact inverse
 against a freshly constructed object, so a resumed run re-executes the
@@ -20,15 +20,13 @@ from __future__ import annotations
 import numpy as np
 
 from repro.checkpoint.checkpoint import restore_like
-from repro.core.scheduler import SummaryRegistry
 from repro.server.events import Event, EventQueue, Stage
 from repro.server.ingest import IngestQueue, SummaryBatch
 from repro.server.refresher import ClusterRefresher, StalenessPolicy
 from repro.server.snapshot import RegistrySnapshot, SnapshotStore, _frozen
 from repro.shard.hierarchy import HierarchicalClusterMaintainer
-from repro.shard.registry import ShardedSummaryRegistry
 from repro.stream.cluster import OnlineClusterMaintainer
-from repro.stream.registry import StreamingSummaryRegistry
+from repro.stream.registry import SummaryRegistry
 
 
 def _opt(a):
@@ -42,76 +40,41 @@ def _expect(cond: bool, what: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# registries (dict / streaming / sharded)
+# the registry
 
 
-def registry_state(reg) -> dict:
-    if isinstance(reg, StreamingSummaryRegistry):   # incl. sharded subclass
-        st = {
-            "kind": ("sharded" if isinstance(reg, ShardedSummaryRegistry)
-                     else "streaming"),
-            "num_clients": int(reg.num_clients),
-            "refresh_count": int(reg.refresh_count),
-            "version": int(reg.version),
-            "last_refresh": reg.last_refresh.copy(),
-            "has_summary": reg.has_summary.copy(),
-            "summaries": _opt(reg.summaries),
-            "label_dists": _opt(reg.label_dists),
-        }
-        if isinstance(reg, ShardedSummaryRegistry):
-            st["scan_chunks"] = int(reg.scan_chunks)
-            st["rechecked_rows"] = int(reg.rechecked_rows)
-        return st
-    if isinstance(reg, SummaryRegistry):
-        # dict-of-arrays contents become (ids, stacked rows): JSON has no
-        # int keys, and npz round-trips the rows bitwise
-        ids = sorted(reg.summaries)
-        return {
-            "kind": "dict",
-            "num_clients": int(reg.num_clients),
-            "refresh_count": int(reg.refresh_count),
-            "version": int(reg.version),
-            "last_refresh": reg.last_refresh.copy(),
-            "has": reg._has.copy(),
-            "ids": np.asarray(ids, np.int64),
-            "summary_rows": (np.stack([reg.summaries[c] for c in ids])
-                             if ids else None),
-            "label_rows": (np.stack([reg.label_dists[c] for c in ids])
-                           if ids else None),
-            "ld_matrix": _opt(reg._ld_matrix),
-            "summary_matrix": _opt(reg._summary_matrix),
-        }
-    raise TypeError(f"unknown registry type {type(reg).__name__}")
+def registry_state(reg: SummaryRegistry) -> dict:
+    # "sharded" is the kind checkpoints carried for this registry when it
+    # was one of three, so those states still restore
+    return {
+        "kind": "sharded",
+        "num_clients": int(reg.num_clients),
+        "refresh_count": int(reg.refresh_count),
+        "version": int(reg.version),
+        "last_refresh": reg.last_refresh.copy(),
+        "has_summary": reg.has_summary.copy(),
+        "summaries": _opt(reg.summaries),
+        "label_dists": _opt(reg.label_dists),
+        "scan_chunks": int(reg.scan_chunks),
+        "rechecked_rows": int(reg.rechecked_rows),
+    }
 
 
-def restore_registry(reg, st: dict) -> None:
-    """Restore serialized registry state into a freshly built registry of
-    the *same* backend (the config owns the backend choice)."""
-    kinds = {SummaryRegistry: "dict", StreamingSummaryRegistry: "streaming",
-             ShardedSummaryRegistry: "sharded"}
-    _expect(st["kind"] == kinds[type(reg)],
-            f"registry backend {kinds[type(reg)]!r} vs "
-            f"checkpointed {st['kind']!r}")
+def restore_registry(reg: SummaryRegistry, st: dict) -> None:
+    """Restore serialized registry state into a freshly built registry."""
+    _expect(st["kind"] == "sharded",
+            f"registry backend 'sharded' vs checkpointed {st['kind']!r}")
     _expect(int(st["num_clients"]) == reg.num_clients,
             f"registry num_clients {reg.num_clients} vs "
             f"checkpointed {st['num_clients']}")
     reg.refresh_count = int(st["refresh_count"])
     reg.version = int(st["version"])
     reg.last_refresh = np.asarray(st["last_refresh"], np.int64)
-    if isinstance(reg, StreamingSummaryRegistry):
-        reg.has_summary = np.asarray(st["has_summary"], bool)
-        reg.summaries = _opt(st["summaries"])
-        reg.label_dists = _opt(st["label_dists"])
-        if isinstance(reg, ShardedSummaryRegistry):
-            reg.scan_chunks = int(st["scan_chunks"])
-            reg.rechecked_rows = int(st["rechecked_rows"])
-        return
-    reg._has = np.asarray(st["has"], bool)
-    ids = [int(c) for c in np.asarray(st["ids"], np.int64)]
-    reg.summaries = {c: st["summary_rows"][i] for i, c in enumerate(ids)}
-    reg.label_dists = {c: st["label_rows"][i] for i, c in enumerate(ids)}
-    reg._ld_matrix = _opt(st["ld_matrix"])
-    reg._summary_matrix = _opt(st["summary_matrix"])
+    reg.has_summary = np.asarray(st["has_summary"], bool)
+    reg.summaries = _opt(st["summaries"])
+    reg.label_dists = _opt(st["label_dists"])
+    reg.scan_chunks = int(st["scan_chunks"])
+    reg.rechecked_rows = int(st["rechecked_rows"])
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,6 @@ from repro.core.kmeans import (  # noqa: F401
 )
 from repro.core.scheduler import (  # noqa: F401
     RefreshPolicy,
-    SummaryRegistry,
     batch_sym_kl,
     sym_kl,
 )

@@ -12,11 +12,11 @@ Table 2).  A client's expensive encoder summary is refreshed when
 — which is how the cheap summary and the paper's efficient summary compose
 into an adaptive refresh policy instead of a fixed period.
 
-``SummaryRegistry`` is the exact-behavior baseline: ``needs_refresh`` is the
-per-client reference predicate, and the hot ``stale_clients`` scan is a
-single batched symmetric-KL over an ``[N, C]`` matrix instead of a Python
-loop (DESIGN.md §5 — ``repro.stream.StreamingSummaryRegistry`` takes the
-same vectorization further by dropping the per-client dicts entirely).
+This module holds the policy and the exact host math: ``sym_kl`` is the
+per-client predicate's drift, ``batch_sym_kl`` the same math row-wise.  The
+registry that applies them to the fleet is ``repro.stream.SummaryRegistry``
+(DESIGN.md §5), whose device scan re-checks borderline rows with
+``batch_sym_kl``.
 """
 from __future__ import annotations
 
@@ -52,118 +52,3 @@ def batch_sym_kl(p: np.ndarray, q: np.ndarray, eps: float = 1e-9) -> np.ndarray:
 class RefreshPolicy:
     max_age_rounds: int = 20
     kl_threshold: float = 0.05
-
-
-class SummaryRegistry:
-    """Server-side store of client summaries + refresh decisions."""
-
-    def __init__(self, num_clients: int, policy: RefreshPolicy):
-        self.policy = policy
-        self.num_clients = num_clients
-        self.summaries: dict[int, np.ndarray] = {}
-        self.label_dists: dict[int, np.ndarray] = {}
-        self.last_refresh = np.full(num_clients, -(10 ** 9), np.int64)
-        self.refresh_count = 0
-        # write-version: bumped on every mutation so the async server's
-        # snapshots can record which registry state they captured
-        # (repro.server.snapshot, DESIGN.md §8)
-        self.version = 0
-        # dense mirrors of ``label_dists`` / ``summaries`` so the stale scan
-        # is one batched sym-KL and ``dense``/``matrix_rows`` are O(1)/O(M)
-        # row reads instead of N python-level calls (allocated on first
-        # update)
-        self._ld_matrix: np.ndarray | None = None
-        self._summary_matrix: np.ndarray | None = None
-        self._has = np.zeros(num_clients, bool)
-
-    def needs_refresh(self, client: int, round_idx: int,
-                      fresh_label_dist: np.ndarray) -> bool:
-        if client not in self.summaries:
-            return True
-        if round_idx - self.last_refresh[client] >= self.policy.max_age_rounds:
-            return True
-        drift = sym_kl(self.label_dists[client], fresh_label_dist)
-        return drift > self.policy.kl_threshold
-
-    def stale_clients(self, round_idx: int, fresh_label_dists,
-                      active: np.ndarray | None = None) -> list:
-        fresh = np.asarray([fresh_label_dists[c]
-                            for c in range(self.num_clients)])
-        return np.flatnonzero(
-            self.stale_mask(round_idx, fresh, active=active)).tolist()
-
-    def stale_mask(self, round_idx: int,
-                   fresh_label_dists: np.ndarray,
-                   active: np.ndarray | None = None) -> np.ndarray:
-        """Vectorized refresh decisions: ``[N, C]`` fresh P(y) -> ``[N]``
-        bool, equal to ``needs_refresh`` evaluated per client.  ``active``
-        (scenario availability threading) restricts decisions to the
-        current fleet — absent clients are never refreshed."""
-        missing = ~self._has
-        aged = (round_idx - self.last_refresh) >= self.policy.max_age_rounds
-        if self._ld_matrix is None:
-            mask = missing | aged
-        else:
-            drift = batch_sym_kl(self._ld_matrix, fresh_label_dists)
-            mask = missing | aged | (drift > self.policy.kl_threshold)
-        if active is not None:
-            mask = mask & np.asarray(active, bool)
-        return mask
-
-    def update(self, client: int, round_idx: int, summary: np.ndarray,
-               label_dist: np.ndarray) -> None:
-        self.summaries[client] = np.asarray(summary)
-        self.label_dists[client] = np.asarray(label_dist)
-        self.last_refresh[client] = round_idx
-        self.refresh_count += 1
-        self.version += 1
-        if self._ld_matrix is None:
-            self._ld_matrix = np.zeros(
-                (self.num_clients, len(self.label_dists[client])),
-                self.label_dists[client].dtype)
-        self._ld_matrix[client] = self.label_dists[client]
-        if self._summary_matrix is None:
-            self._summary_matrix = np.zeros(
-                (self.num_clients, len(self.summaries[client])),
-                self.summaries[client].dtype)
-        self._summary_matrix[client] = self.summaries[client]
-        self._has[client] = True
-
-    def remove(self, client: int) -> None:
-        """Evict a departed client (scenario churn): its summary and cheap
-        drift row must stop participating in scans and clustering, and a
-        rejoin must look like a brand-new client (missing ⇒ stale)."""
-        self.summaries.pop(client, None)
-        self.label_dists.pop(client, None)
-        self.last_refresh[client] = -(10 ** 9)
-        self._has[client] = False
-        self.version += 1
-        if self._ld_matrix is not None:
-            self._ld_matrix[client] = 0.0
-        if self._summary_matrix is not None:
-            self._summary_matrix[client] = 0.0
-
-    def has_mask(self) -> np.ndarray:
-        """[N] bool: which clients currently hold a summary."""
-        return self._has.copy()
-
-    def matrix(self) -> np.ndarray:
-        """Stack all summaries into the clustering input [N, D]."""
-        assert len(self.summaries) == self.num_clients, "missing summaries"
-        return np.stack([self.summaries[c] for c in range(self.num_clients)])
-
-    def matrix_rows(self, ids: np.ndarray) -> np.ndarray:
-        """Clustering input restricted to ``ids`` (all must hold
-        summaries) — the churn-safe variant of ``matrix``."""
-        ids = np.asarray(ids, np.int64)
-        if self._summary_matrix is None or ids.size == 0:
-            return np.zeros((0, 0), np.float32)
-        assert self._has[ids].all(), "missing summaries in requested rows"
-        return self._summary_matrix[ids]
-
-    def dense(self) -> np.ndarray:
-        """Full [N, D] matrix with zero rows for missing clients (online
-        cluster maintenance needs stable row indexing under churn) — the
-        live dense mirror, no per-round re-stacking."""
-        assert self._summary_matrix is not None, "no summaries yet"
-        return self._summary_matrix

@@ -16,10 +16,11 @@ Per round (stage semantics shared by both servers via ``RoundContext``):
      speeds/availability, label-drift positions, deadline and dropout draws,
   2. departed clients are evicted from the summary registry,
   3. summary refresh: the registry decides which *active* clients are stale
-     (age or cheap-P(y)-drift); stale clients recompute the configured
-     summary — by default through the fleet-scale batched engine (one jitted
-     dispatch per shape bucket, DESIGN.md §4) — and the measured seconds are
-     charged to the simulated clock,
+     (age or cheap-P(y)-drift, one chunked device scan, DESIGN.md §5/§7);
+     stale clients recompute the configured summary through the
+     fleet-scale batched engine (one jitted dispatch per shape bucket,
+     DESIGN.md §4), and the measured seconds are charged to the simulated
+     clock,
   4. (re-)cluster the summaries of active clients with K-means (or DBSCAN;
      ``online`` keeps assignments fresh with O(drifted) work per round and
      only refits when inertia degrades — DESIGN.md §5),
@@ -53,17 +54,15 @@ import repro.obs as obs
 from repro.checkpoint.durable import Durability, DurableSession
 from repro.checkpoint.server_state import context_state, restore_context
 from repro.core import (
-    BatchedSummaryEngine, RefreshPolicy, SummaryRegistry,
-    dbscan, kmeans, minibatch_kmeans, sym_kl,
+    BatchedSummaryEngine, RefreshPolicy, dbscan, kmeans, minibatch_kmeans,
+    sym_kl,
 )
 from repro.policies import ClientStats, PolicyContext, make_policy
-from repro.shard import HierarchicalClusterMaintainer, ShardedSummaryRegistry
-from repro.stream import (
-    OnlineClusterMaintainer, OnlinePolicy, StreamingSummaryRegistry,
-)
+from repro.shard import HierarchicalClusterMaintainer
+from repro.stream import OnlineClusterMaintainer, OnlinePolicy, SummaryRegistry
 from repro.data.synthetic import FederatedDataset
 from repro.fl.aggregation import fedavg
-from repro.fl.client import ClientRuntime, local_train, timed_summary
+from repro.fl.client import ClientRuntime, local_train
 from repro.fl.models import make_classifier, xent_loss
 from repro.fl.system import SystemModel, SystemSpec, completion_times
 from repro.kernels.ops import on_tpu
@@ -91,20 +90,19 @@ class FLConfig:
                                      # haccs | random | fastest |
                                      # grad-importance | grey-relational |
                                      # oort | ... (DESIGN.md §11)
-    summary_engine: str = "batched"  # batched (one dispatch per bucket) |
-                                     # perclient (legacy per-client jit loop)
-    registry: str = "dict"           # dict (baseline SummaryRegistry) |
-                                     # streaming (dense [N,·] matrices,
-                                     # batched drift scan, DESIGN.md §5) |
-                                     # sharded (chunked drift scan over a
-                                     # fleet device mesh, DESIGN.md §7)
+    # one summary engine (one dispatch per shape bucket, DESIGN.md §4)
+    # and one registry (dense arenas, chunked drift scan over a fleet
+    # device mesh, DESIGN.md §5/§7); each field accepts only its one
+    # value, which the benchmark's configurations name
+    summary_engine: str = "batched"
+    registry: str = "sharded"
     clustering: str = "kmeans"       # kmeans | minibatch | dbscan |
                                      # online (assign-only maintenance) |
                                      # hierarchical (shard-local online
                                      # + weighted global merge, §7)
     online_inertia_ratio: float = 1.5   # online: full-refit trigger
     online_reseed_every: int = 8        # online: split/merge cadence
-    # --- sharded fleet pipeline (DESIGN.md §7) ---
+    # --- fleet mesh (DESIGN.md §7) ---
     n_shards: int = 0                # 0 = one shard per local device
     shard_chunk_rows: int = 131072   # scan chunk (caps device memory)
     hier_local_k: int = 0            # per-shard centroids (0 = num_clusters)
@@ -158,6 +156,12 @@ class FLConfig:
     # --- eval ---
     eval_every: int = 1
     seed: int = 0
+
+    def __post_init__(self):
+        for field, only in (("summary_engine", "batched"),
+                            ("registry", "sharded")):
+            if getattr(self, field) != only:
+                raise ValueError(f"unknown {field}: {getattr(self, field)}")
 
 
 class LegacySystemScenario:
@@ -262,27 +266,17 @@ class RoundContext:
         # the Pallas kernels on the chip, the jnp references elsewhere
         self.use_kernel = on_tpu()
 
-        if cfg.summary_engine not in ("batched", "perclient"):
-            raise ValueError(f"unknown summary_engine: {cfg.summary_engine}")
         self.engine = None
-        if cfg.summary != "none" and cfg.summary_engine == "batched":
+        if cfg.summary != "none":
             self.engine = BatchedSummaryEngine(
                 cfg.summary, spec.num_classes, encoder_fn=self.enc_fn,
                 coreset_k=cfg.coreset_k, bins=cfg.bins,
                 use_kernel=self.use_kernel, encoder_dim=cfg.encoder_dim)
-        policy = RefreshPolicy(cfg.refresh_max_age, cfg.refresh_kl)
-        if cfg.registry == "streaming":
-            self.registry = StreamingSummaryRegistry(
-                spec.num_clients, policy, num_classes=spec.num_classes)
-        elif cfg.registry == "sharded":
-            self.registry = ShardedSummaryRegistry(
-                spec.num_clients, policy, num_classes=spec.num_classes,
-                n_shards=cfg.n_shards or None,
-                chunk_rows=cfg.shard_chunk_rows)
-        elif cfg.registry == "dict":
-            self.registry = SummaryRegistry(spec.num_clients, policy)
-        else:
-            raise ValueError(f"unknown registry: {cfg.registry}")
+        self.registry = SummaryRegistry(
+            spec.num_clients,
+            RefreshPolicy(cfg.refresh_max_age, cfg.refresh_kl),
+            num_classes=spec.num_classes, n_shards=cfg.n_shards or None,
+            chunk_rows=cfg.shard_chunk_rows)
         if cfg.clustering not in ("kmeans", "minibatch", "dbscan", "online",
                                   "hierarchical"):
             raise ValueError(f"unknown clustering: {cfg.clustering}")
@@ -401,10 +395,10 @@ class RoundContext:
         Pure compute — nothing is written to the registry here, so the
         async server can hold results in its ingest queue.  Each client's
         PRNG seed is a pure function of (round, client),
-        ``rnd * 100003 + c``: the batched engine makes
-        ``jax.random.PRNGKey(seed)`` inside its dispatch, the per-client
-        path makes the same key on the host, so the two stay
-        bitwise-identical, and so do sync and async servers.
+        ``rnd * 100003 + c``, which the batched engine turns into
+        ``jax.random.PRNGKey(seed)`` inside its dispatch, so sync and async
+        servers stay bitwise-identical (and so does the per-client
+        baseline ``fl.client.timed_summary`` given the same key).
         """
         summaries: dict[int, np.ndarray] = {}
         times: dict[int, float] = {}
@@ -413,12 +407,6 @@ class RoundContext:
             return summaries, times, wall
         with obs.span("client_summaries", cat="client", round=rnd,
                       n_stale=len(stale)):
-            self._compute_summaries(rnd, stale, drift, summaries, times)
-        wall = sum(times.values())
-        return summaries, times, wall
-
-    def _compute_summaries(self, rnd, stale, drift, summaries, times):
-        if self.engine is not None:
             results = self.engine.summarize_clients(
                 stale, self.data.sizes,
                 lambda c: self.data.client_data(c, float(drift[c])),
@@ -426,18 +414,8 @@ class RoundContext:
             for c, res in results.items():
                 summaries[c] = res.summary
                 times[c] = res.seconds
-        else:
-            cfg = self.cfg
-            for c in stale:
-                feats, labels, valid = self.data.client_data(
-                    c, float(drift[c]))
-                s, _ld_emp, dt = timed_summary(
-                    cfg.summary, feats, labels, valid, self.spec.num_classes,
-                    encoder_fn=self.enc_fn, coreset_k=cfg.coreset_k,
-                    bins=cfg.bins, use_kernel=self.use_kernel,
-                    key=jax.random.PRNGKey(rnd * 100003 + c))
-                summaries[c] = s
-                times[c] = dt
+        wall = sum(times.values())
+        return summaries, times, wall
 
     # ------------------------------------------------------------------
     # stage: registry ingest (O(M) scatter)
@@ -454,14 +432,10 @@ class RoundContext:
             return
         with obs.span("registry_scatter", round=rnd, batch=len(summaries)):
             t0 = time.perf_counter()
-            if isinstance(self.registry, StreamingSummaryRegistry):
-                ids = list(summaries)
-                self.registry.update_batch(
-                    ids, rnd, np.stack([summaries[c] for c in ids]),
-                    np.stack([fresh_rows[c] for c in ids]))
-            else:
-                for c, s in summaries.items():
-                    self.registry.update(c, rnd, s, fresh_rows[c])
+            ids = list(summaries)
+            self.registry.update_batch(
+                ids, rnd, np.stack([summaries[c] for c in ids]),
+                np.stack([fresh_rows[c] for c in ids]))
             self._meters.add("drain", time.perf_counter() - t0)
 
     # ------------------------------------------------------------------
@@ -848,7 +822,7 @@ def _drive_sync(ctx: RoundContext, session=None, faults=None,
         if session is not None:
             session.commit_round(
                 rnd, cfg.rounds, sel,
-                registry_version=getattr(ctx.registry, "version", 0),
+                registry_version=ctx.registry.version,
                 snapshot_version=ctx.recluster_count,
                 state_fn=lambda: {"round": rnd,
                                   "context": context_state(ctx)})

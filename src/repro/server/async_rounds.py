@@ -280,7 +280,7 @@ def drive_async(ctx, session=None, faults=None, start_round: int = 0,
             rnd = ev.payload
             session.commit_round(
                 rnd, cfg.rounds, ctx.history["selected"][-1],
-                registry_version=getattr(ctx.registry, "version", 0),
+                registry_version=ctx.registry.version,
                 snapshot_version=store.version,
                 state_fn=lambda: {
                     "round": rnd,

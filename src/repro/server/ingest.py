@@ -4,8 +4,7 @@ Client summary recomputation finishes *somewhere else* — on the device, a
 network round-trip away.  The async server models that with an explicit
 queue: a batch computed at round ``r`` becomes ready at round
 ``r + delay`` and only then scatters into the live registry (the same
-O(M) ``RoundContext.ingest`` write the sync loop uses, against whichever
-registry backend — dict / streaming / sharded — is configured).
+O(M) ``RoundContext.ingest`` row scatter the sync loop uses).
 
 Two invariants matter for the pipeline:
 

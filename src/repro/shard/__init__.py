@@ -1,10 +1,10 @@
-"""Sharded fleet pipeline (DESIGN.md §7).
+"""Hierarchical fleet clustering (DESIGN.md §7).
 
-Partitions the summary→drift-scan→clustering server round across a JAX
-device mesh: a row-sharded, chunk-scanned summary registry
-(``registry.py``) and hierarchical two-level clustering
-(``hierarchy.py``), wired into the round loop behind
-``FLConfig(registry="sharded", clustering="hierarchical")``.
+Two-level clustering over row slices of the fleet (``hierarchy.py``):
+each slice keeps its own online maintainer and a weighted global merge
+turns the slice-local centroids into the fleet's, wired into the round
+loop behind ``FLConfig(clustering="hierarchical")``.  The drift scan over
+the ``fleet`` device mesh lives with the registry
+(``repro.stream.SummaryRegistry``).
 """
 from repro.shard.hierarchy import HierarchicalClusterMaintainer  # noqa: F401
-from repro.shard.registry import ShardedSummaryRegistry  # noqa: F401

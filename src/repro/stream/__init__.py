@@ -1,16 +1,16 @@
 """Streaming sketch summaries & online clustering (DESIGN.md §5).
 
 Fleet-scale server-side state: mergeable count-min label sketches and
-random-projection feature sketches (``sketch.py``), a vectorized streaming
-summary registry with batched drift detection (``registry.py``), and an
-online cluster maintainer that keeps assignments fresh with O(drifted)
+random-projection feature sketches (``sketch.py``), the fleet's summary
+registry, whose drift scan runs chunked on a device mesh (``registry.py``),
+and an online cluster maintainer that keeps assignments fresh with O(drifted)
 work per round (``cluster.py``).
 """
 from repro.stream.cluster import (  # noqa: F401
     OnlineClusterMaintainer,
     OnlinePolicy,
 )
-from repro.stream.registry import StreamingSummaryRegistry  # noqa: F401
+from repro.stream.registry import SummaryRegistry  # noqa: F401
 from repro.stream.sketch import (  # noqa: F401
     FleetSketches,
     SketchSpec,

@@ -18,7 +18,7 @@ Both update paths are one-hot × one-hot (or plain) matmuls, so the batched
 update fuses across clients via the label-offset trick — on TPU through the
 ``sketch_update`` Pallas kernel (``kernels/sketch_update.py``), elsewhere
 through the pure-jnp oracle.  ``update`` returns *increments*; ``merge`` is
-addition — the algebra the streaming registry leans on.
+addition — the algebra the streaming subsystem leans on.
 """
 from __future__ import annotations
 

@@ -50,8 +50,9 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
 }
 
 
-# The server-side fleet pipeline (src/repro/shard/) partitions client-row
-# arenas over a dedicated 1-D `fleet` mesh axis instead of the model axes.
+# The registry's drift scan (src/repro/stream/registry.py) partitions
+# client-row arenas over a dedicated 1-D `fleet` mesh axis instead of the
+# model axes.
 FLEET_RULES: dict[str, tuple[str, ...]] = {"clients": ("fleet",)}
 
 
@@ -59,8 +60,8 @@ def fleet_mesh(n_devices: int | None = None) -> Mesh:
     """1-D mesh over the local devices with a single ``fleet`` axis.
 
     ``n_devices`` is clamped to what the host actually has, so configs
-    written for a 4-device CI host degrade to a 1-device mesh (and thus to
-    the streaming baseline's semantics) on a laptop instead of failing.
+    written for a 4-device CI host degrade to a 1-device mesh on a laptop
+    instead of failing.
     """
     devs = jax.devices()
     n = len(devs) if n_devices is None else max(1, min(n_devices, len(devs)))

@@ -8,6 +8,7 @@ the same executor, so their histories match bitwise.
 """
 import dataclasses
 import json
+import pathlib
 
 import pytest
 
@@ -23,8 +24,6 @@ from repro.fl.rounds import FLConfig, run_federated
 @pytest.mark.parametrize("kw,msg", [
     (dict(model="resnet"), "unknown model: resnet"),
     (dict(summary="sketch"), "unknown summary: sketch"),
-    (dict(summary_engine="fused"), "unknown summary_engine: fused"),
-    (dict(registry={"kind": "redis"}), "unknown registry: redis"),
     (dict(clustering={"kind": "spectral"}), "unknown clustering: spectral"),
     (dict(server={"kind": "threads"}), "unknown server: threads"),
     (dict(server={"kind": "async", "refresh": "eager"}),
@@ -61,16 +60,13 @@ def test_range_validation(kw, msg):
 
 
 def test_cross_field_contracts():
-    with pytest.raises(ValueError, match="requires registry=sharded"):
-        api.RunConfig(clustering={"kind": "hierarchical"})
     with pytest.raises(ValueError, match="requires server=async"):
         api.RunConfig(server={"kind": "sync",
                               "frontend": {"kind": "poisson"}})
     with pytest.raises(ValueError, match="requires server=async"):
         api.RunConfig(server={"kind": "sync", "refresh": "staleness"})
     # the coherent combinations construct fine
-    api.RunConfig(registry={"kind": "sharded"},
-                  clustering={"kind": "hierarchical"})
+    api.RunConfig(clustering={"kind": "hierarchical"})
     api.RunConfig(server={"kind": "async", "refresh": "staleness",
                           "frontend": {"kind": "poisson"}})
 
@@ -94,11 +90,11 @@ def test_subconfig_type_errors():
 
 def test_mapping_coercion_matches_explicit_subconfigs():
     a = api.RunConfig(server={"kind": "async", "refresh": "staleness"},
-                      registry={"kind": "sharded", "n_shards": 2})
+                      registry={"n_shards": 2})
     b = api.RunConfig(
         server=api.ServerConfig(kind=api.Server.ASYNC,
                                 refresh=api.Refresh.STALENESS),
-        registry=api.RegistryConfig(kind=api.Registry.SHARDED, n_shards=2))
+        registry=api.RegistryConfig(n_shards=2))
     assert a == b
 
 
@@ -110,7 +106,7 @@ def _rich_config(**kw):
     base = dict(
         rounds=5, clients_per_round=6, local_steps=2, lr=0.1,
         summary="py", bins=6, refresh_max_age=4, refresh_kl=0.07,
-        registry={"kind": "sharded", "n_shards": 2, "chunk_rows": 64},
+        registry={"n_shards": 2, "chunk_rows": 64},
         clustering={"kind": "hierarchical", "num_clusters": 4,
                     "recluster_every": 3, "hier_local_k": 2},
         server={"kind": "async", "refresh": "staleness",
@@ -163,8 +159,46 @@ def test_from_dict_rejects_unknown_fields():
 
 def test_replace_revalidates():
     cfg = _rich_config()
-    with pytest.raises(ValueError, match="requires registry=sharded"):
-        dataclasses.replace(cfg, registry=api.RegistryConfig())
+    with pytest.raises(ValueError, match="requires server=async"):
+        dataclasses.replace(cfg, server=dataclasses.replace(cfg.server,
+                                                            kind="sync"))
+
+
+# ---------------------------------------------------------------------------
+# one registry and one summary engine: the removed values are refused
+
+
+@pytest.mark.parametrize("build,msg", [
+    (lambda: FLConfig(registry="dict"), "unknown registry: dict"),
+    (lambda: FLConfig(registry="streaming"), "unknown registry: streaming"),
+    (lambda: FLConfig(summary_engine="perclient"),
+     "unknown summary_engine: perclient"),
+    (lambda: api.RunConfig.from_dict({"summary_engine": "perclient"}),
+     r"unknown RunConfig fields: \['summary_engine'\]"),
+    (lambda: api.RunConfig.from_dict({"registry": {"kind": "dict"}}),
+     r"unknown RunConfig fields: \['registry.kind'\]"),
+    (lambda: api.RunConfig.from_dict({"registry": {"kind": "streaming"}}),
+     r"unknown RunConfig fields: \['registry.kind'\]"),
+], ids=["fl-dict", "fl-streaming", "fl-perclient", "run-perclient",
+        "run-dict", "run-streaming"])
+def test_removed_registries_and_engine_refused(build, msg):
+    with pytest.raises(ValueError, match=msg):
+        build()
+
+
+@pytest.mark.parametrize("path", sorted(
+    (pathlib.Path(__file__).resolve().parent.parent
+     / "chipbench" / "configs").glob("*.json")), ids=lambda p: p.stem)
+def test_benchmark_server_groups_build_flconfig(path):
+    """The benchmark builds ``FLConfig(**cfg["server"])``: each
+    configuration's server group names the one registry and the one
+    engine, which are also the defaults."""
+    server = json.loads(path.read_text())["server"]
+    cfg = FLConfig(**server)
+    assert (cfg.registry, cfg.summary_engine) == ("sharded", "batched")
+    assert (FLConfig().registry, FLConfig().summary_engine) == \
+        (cfg.registry, cfg.summary_engine)
+    assert api.RunConfig.from_flconfig(cfg).to_flconfig() == cfg
 
 
 # ---------------------------------------------------------------------------

@@ -11,13 +11,14 @@ import pytest
 
 import repro.obs as obs
 from repro.core import (
-    BatchedSummaryEngine, RefreshPolicy, SummaryRegistry,
-    batched_per_label_mean, batched_pxy_histogram, bucket_size,
+    BatchedSummaryEngine, RefreshPolicy, batched_per_label_mean,
+    batched_pxy_histogram, bucket_size,
 )
 from repro.core.batched_summary import seed_keys
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl.client import timed_summary
 from repro.models.cnn import CNNConfig, build_cnn, cnn_apply
+from repro.stream import SummaryRegistry
 
 
 @pytest.fixture(scope="module")
@@ -82,7 +83,8 @@ def test_registry_bookkeeping_unchanged(data, enc_fn):
     policy = RefreshPolicy(max_age_rounds=10, kl_threshold=0.05)
     reg_a = SummaryRegistry(spec.num_clients, policy)
     reg_b = SummaryRegistry(spec.num_clients, policy)
-    fresh = {c: data.client_label_dist(c) for c in range(spec.num_clients)}
+    fresh = np.stack([data.client_label_dist(c)
+                      for c in range(spec.num_clients)])
     rnd = 0
 
     stale_a = reg_a.stale_clients(rnd, fresh)
@@ -92,20 +94,22 @@ def test_registry_bookkeeping_unchanged(data, enc_fn):
                                  spec.num_classes, encoder_fn=enc_fn,
                                  coreset_k=16, bins=8,
                                  key=jax.random.PRNGKey(1000 + c))
-        reg_a.update(c, rnd, s, fresh[c])
+        reg_a.update_batch([c], rnd, s[None], fresh[c][None])
 
     engine = BatchedSummaryEngine("encoder", spec.num_classes,
                                   encoder_fn=enc_fn, coreset_k=16, bins=8)
     stale_b = reg_b.stale_clients(rnd, fresh)
-    assert stale_b == stale_a
-    for c, res in engine.summarize(_items(data)).items():
-        reg_b.update(c, rnd, res.summary, fresh[c])
+    np.testing.assert_array_equal(stale_b, stale_a)
+    results = engine.summarize(_items(data))
+    ids = list(results)
+    reg_b.update_batch(ids, rnd, np.stack([results[c].summary for c in ids]),
+                       fresh[ids])
 
     assert reg_b.refresh_count == reg_a.refresh_count
     np.testing.assert_array_equal(reg_b.last_refresh, reg_a.last_refresh)
-    np.testing.assert_allclose(reg_b.matrix(), reg_a.matrix(), atol=1e-5)
+    np.testing.assert_allclose(reg_b.dense(), reg_a.dense(), atol=1e-5)
     # neither registry considers anyone stale right after the refresh
-    assert reg_b.stale_clients(rnd + 1, fresh) == []
+    assert reg_b.stale_clients(rnd + 1, fresh).size == 0
 
 
 @pytest.mark.parametrize("fn,extra", [

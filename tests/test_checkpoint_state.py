@@ -1,8 +1,8 @@
-"""Checkpoint round-trip tests (DESIGN.md §9): every registry flavor,
-both cluster maintainers, snapshots and the driver RNG serialize through
-``checkpoint.save_state``/``load_state`` and restore *bitwise* — version
-counters, ``has_mask``, ``matrix()`` bytes, and future behavior all
-identical."""
+"""Checkpoint round-trip tests (DESIGN.md §9): the registry under every
+scan layout, both cluster maintainers, snapshots and the driver RNG
+serialize through ``checkpoint.save_state``/``load_state`` and restore
+*bitwise* — version counters, ``has_mask``, ``dense()`` bytes, and future
+behavior all identical."""
 import os
 
 import jax
@@ -14,23 +14,18 @@ from repro.checkpoint import (
     restore_registry, restore_snapshot, save_state, snapshot_state,
 )
 from repro.checkpoint.server_state import restore_rng, rng_state
-from repro.core import RefreshPolicy, SummaryRegistry
+from repro.core import RefreshPolicy
 from repro.server.snapshot import capture
-from repro.shard import HierarchicalClusterMaintainer, ShardedSummaryRegistry
-from repro.stream import (
-    OnlineClusterMaintainer, OnlinePolicy, StreamingSummaryRegistry,
-)
+from repro.shard import HierarchicalClusterMaintainer
+from repro.stream import OnlineClusterMaintainer, OnlinePolicy, SummaryRegistry
+from scan_layouts import REGISTRY as LAYOUTS
 
 N, C, D = 20, 5, 8
 POLICY = RefreshPolicy(max_age_rounds=4, kl_threshold=0.08)
 
 
-def _mk_registry(kind):
-    if kind == "dict":
-        return SummaryRegistry(N, POLICY)
-    if kind == "streaming":
-        return StreamingSummaryRegistry(N, POLICY, num_classes=C)
-    return ShardedSummaryRegistry(N, POLICY, num_classes=C, chunk_rows=8)
+def _mk_registry(layout):
+    return SummaryRegistry(N, POLICY, num_classes=C, **LAYOUTS[layout])
 
 
 def _populate(reg, seed, rounds=3):
@@ -43,11 +38,7 @@ def _populate(reg, seed, rounds=3):
                if rs.rand() > 0.25]
         if ids:
             summaries = rs.rand(len(ids), D).astype(np.float32)
-            if isinstance(reg, StreamingSummaryRegistry):
-                reg.update_batch(ids, rnd, summaries, fresh[ids])
-            else:
-                for i, cl in enumerate(ids):
-                    reg.update(cl, rnd, summaries[i], fresh[cl])
+            reg.update_batch(ids, rnd, summaries, fresh[ids])
         if rs.rand() > 0.5:
             reg.remove(int(rs.randint(N)))
     return rs
@@ -105,17 +96,17 @@ def test_save_state_rejects_unserializable(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# registries: dict / streaming / sharded
+# the registry, under each scan layout
 
 
-@pytest.mark.parametrize("kind", ["dict", "streaming", "sharded"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("seed", range(5))
-def test_registry_roundtrip(tmp_path, kind, seed):
-    reg = _mk_registry(kind)
+def test_registry_roundtrip(tmp_path, layout, seed):
+    reg = _mk_registry(layout)
     rs = _populate(reg, seed)
     base = os.path.join(str(tmp_path), "reg")
     save_state(base, {"registry": registry_state(reg)})
-    fresh_reg = _mk_registry(kind)
+    fresh_reg = _mk_registry(layout)
     restore_registry(fresh_reg, load_state(base)["registry"])
 
     assert fresh_reg.version == reg.version
@@ -132,41 +123,37 @@ def test_registry_roundtrip(tmp_path, kind, seed):
     fresh = rs.dirichlet([0.4] * C, N).astype(np.float32)
     np.testing.assert_array_equal(fresh_reg.stale_mask(7, fresh),
                                   reg.stale_mask(7, fresh))
-    if kind == "dict":
-        assert set(fresh_reg.summaries) == set(reg.summaries)
-        for cl in reg.summaries:
-            np.testing.assert_array_equal(fresh_reg.summaries[cl],
-                                          reg.summaries[cl])
-    if kind == "sharded":
-        assert fresh_reg.scan_chunks == reg.scan_chunks
-        assert fresh_reg.rechecked_rows == reg.rechecked_rows
+    assert fresh_reg.scan_chunks == reg.scan_chunks
+    assert fresh_reg.rechecked_rows == reg.rechecked_rows
 
 
 def test_registry_full_matrix_bytes(tmp_path):
-    """With every client populated, the full ``matrix()`` round-trips
-    bitwise for both backends."""
-    for kind in ("dict", "streaming"):
-        reg = _mk_registry(kind)
+    """With every client populated, the full ``dense()`` matrix
+    round-trips bitwise under every layout."""
+    for layout in LAYOUTS:
+        reg = _mk_registry(layout)
         rs = np.random.RandomState(0)
         fresh = rs.dirichlet([0.4] * C, N).astype(np.float32)
-        for cl in range(N):
-            reg.update(cl, 0, rs.rand(D).astype(np.float32), fresh[cl])
-        base = os.path.join(str(tmp_path), f"full-{kind}")
+        reg.update_batch(np.arange(N), 0,
+                         rs.rand(N, D).astype(np.float32), fresh)
+        base = os.path.join(str(tmp_path), f"full-{layout}")
         save_state(base, registry_state(reg))
-        other = _mk_registry(kind)
+        other = _mk_registry(layout)
         restore_registry(other, load_state(base))
-        assert other.matrix().tobytes() == reg.matrix().tobytes()
+        assert other.has_mask().all()
+        assert other.dense().tobytes() == reg.dense().tobytes()
 
 
 def test_registry_restore_mismatch_fails(tmp_path):
-    reg = _mk_registry("streaming")
+    reg = _mk_registry("padded")
     _populate(reg, 0)
     st = registry_state(reg)
-    with pytest.raises(ValueError, match="backend"):
-        restore_registry(_mk_registry("dict"), st)
+    # states of the removed dict and streaming registries are refused
+    for kind in ("dict", "streaming"):
+        with pytest.raises(ValueError, match="backend"):
+            restore_registry(_mk_registry("padded"), dict(st, kind=kind))
     with pytest.raises(ValueError, match="num_clients"):
-        restore_registry(
-            StreamingSummaryRegistry(N + 1, POLICY, num_classes=C), st)
+        restore_registry(SummaryRegistry(N + 1, POLICY, num_classes=C), st)
 
 
 # ---------------------------------------------------------------------------
@@ -231,7 +218,7 @@ def test_maintainer_none_roundtrip():
 
 
 def test_snapshot_roundtrip(tmp_path):
-    reg = _mk_registry("streaming")
+    reg = _mk_registry("padded")
     _populate(reg, 2)
     snap = capture(5, 3, reg, np.arange(N) % 3, 3, drift_mass=0.25)
     base = os.path.join(str(tmp_path), "snap")

@@ -2,112 +2,115 @@
 to their exact baselines across >=20 random seeds, including under scenario
 churn (clients appearing/disappearing between rounds).
 
-  * ``streaming`` registry staleness decisions, refresh sets, and stored
-    state exactly match the ``dict`` baseline round for round;
+  * the registry's staleness decisions match a plain per-client
+    ``sym_kl`` loop round for round, and a multi-chunk scan with a
+    zero-padded tail matches the whole-fleet scan in decisions and state;
   * ``batched`` engine summaries bitwise-match the per-client
-    ``timed_summary`` path (same bucket padding, same PRNG keys);
-  * end-to-end: swapping registry (dict vs streaming) or engine (batched vs
-    perclient) leaves the round loop's selection/refresh/accuracy traces
-    identical under a churn scenario;
+    ``timed_summary`` baseline (same bucket padding, same PRNG keys);
+  * end-to-end: a chunked scan and an all-rechecked scan leave the round
+    loop's selection/refresh/accuracy traces identical under a churn
+    scenario;
   * the async selection server (``server="async"``, zero ingest latency,
     sync refresh cadence — DESIGN.md §8) replays the sync trace bitwise
-    for every registry backend (24-seed matrix in ``tests/test_server.py``).
+    for every scan layout (24-seed matrix in ``tests/test_server.py``).
 """
+import functools
+
 import jax
 import numpy as np
 import pytest
 
-from repro.core import BatchedSummaryEngine, RefreshPolicy, SummaryRegistry
+from repro.core import BatchedSummaryEngine, RefreshPolicy, sym_kl
 from repro.data.synthetic import FederatedDataset, small_spec
-from repro.fl import FLConfig, run_federated
+from repro.fl import FLConfig, rounds, run_federated
 from repro.fl.client import timed_summary
-from repro.shard import ShardedSummaryRegistry
 from repro.sim import Scenario, make_scenario
-from repro.stream import StreamingSummaryRegistry
+from repro.stream import SummaryRegistry
+from scan_layouts import FLCONFIG
 
 SEEDS = range(24)          # >= 20 random seeds (acceptance floor)
 
 
 # ---------------------------------------------------------------------------
-# streaming registry ≡ dict baseline, under churn
+# registry ≡ a plain per-client sym_kl loop, under churn
 
 
 @pytest.mark.parametrize("seed", SEEDS)
-def test_streaming_decisions_match_dict_under_churn(seed):
+def test_registry_decisions_match_per_client_loop_under_churn(seed):
     n, c, rounds = 30, 6, 10
     rs = np.random.RandomState(seed)
     policy = RefreshPolicy(max_age_rounds=4, kl_threshold=0.08)
-    base = SummaryRegistry(n, policy)
-    stream = StreamingSummaryRegistry(n, policy)
+    reg = SummaryRegistry(n, policy)
+    # the reference: the stored P(y) and refresh round of every client
+    # that holds a summary, kept apart from the registry's arenas
+    ref_ld, ref_round = {}, {}
     scenario = make_scenario("mobile-churn", n, seed=seed)
     for rnd in range(rounds):
         plan = scenario.round_plan(rnd)
         for cl in plan.departed:
-            base.remove(int(cl))
-            stream.remove(int(cl))
+            reg.remove(int(cl))
+            ref_ld.pop(int(cl), None)
+            ref_round.pop(int(cl), None)
         fresh = rs.dirichlet([0.4] * c, n).astype(np.float32)
-        # baseline mask == per-client reference predicate, gated by the fleet
-        want = base.stale_mask(rnd, fresh, active=plan.active)
-        ref = np.asarray([base.needs_refresh(cl, rnd, fresh[cl])
-                          for cl in range(n)]) & plan.active
-        np.testing.assert_array_equal(want, ref)
-        # streaming refresh set == dict refresh set, exactly
-        got = stream.stale_clients(rnd, fresh, active=plan.active)
-        np.testing.assert_array_equal(got, np.flatnonzero(want))
+        want = [cl for cl in range(n) if plan.active[cl] and (
+            cl not in ref_ld
+            or rnd - ref_round[cl] >= policy.max_age_rounds
+            or sym_kl(ref_ld[cl], fresh[cl]) > policy.kl_threshold)]
+        got = reg.stale_clients(rnd, fresh, active=plan.active)
+        np.testing.assert_array_equal(got, want)
         # refresh a random subset (partial rounds), same on both sides
         todo = [int(cl) for cl in got if rs.rand() > 0.25]
         if todo:
             summaries = rs.rand(len(todo), 8).astype(np.float32)
-            stream.update_batch(todo, rnd, summaries, fresh[todo])
+            reg.update_batch(todo, rnd, summaries, fresh[todo])
             for i, cl in enumerate(todo):
-                base.update(cl, rnd, summaries[i], fresh[cl])
-        assert stream.refresh_count == base.refresh_count
-        np.testing.assert_array_equal(stream.has_mask(), base.has_mask())
-        np.testing.assert_array_equal(stream.last_refresh, base.last_refresh)
-        have = np.flatnonzero(stream.has_mask())
-        if have.size:
-            np.testing.assert_array_equal(stream.matrix_rows(have),
-                                          base.matrix_rows(have))
+                ref_ld[cl], ref_round[cl] = fresh[cl], rnd
+        np.testing.assert_array_equal(np.flatnonzero(reg.has_mask()),
+                                      sorted(ref_ld))
+        for cl, r in ref_round.items():
+            assert reg.last_refresh[cl] == r
+            np.testing.assert_array_equal(reg.label_dists[cl], ref_ld[cl])
 
 
 # ---------------------------------------------------------------------------
-# sharded registry ≡ streaming baseline, under churn (DESIGN.md §7) — on
-# whatever mesh the host exposes (1 device here; CI re-runs the shard
+# padded multi-chunk scan ≡ whole-fleet scan, under churn (DESIGN.md §7) —
+# on whatever mesh the host exposes (1 device here; CI re-runs the shard
 # tests on a forced 4-device host)
 
 
 @pytest.mark.parametrize("seed", SEEDS)
-def test_sharded_decisions_match_streaming_under_churn(seed):
+def test_padded_chunks_match_whole_fleet_under_churn(seed):
     n, c, rounds = 30, 6, 10
     rs = np.random.RandomState(seed)
     policy = RefreshPolicy(max_age_rounds=4, kl_threshold=0.08)
-    stream = StreamingSummaryRegistry(n, policy)
+    whole = SummaryRegistry(n, policy)
     # chunk_rows=8: the fleet spans multiple chunks + a zero-padded tail,
     # so the differential covers the chunked-scan path, not just 1 chunk
-    shard = ShardedSummaryRegistry(n, policy, chunk_rows=8)
+    chunked = SummaryRegistry(n, policy, chunk_rows=8)
+    assert whole.chunk_rows >= n > 3 * chunked.chunk_rows   # 1 vs 4 chunks
     scenario = make_scenario("mobile-churn", n, seed=seed)
     for rnd in range(rounds):
         plan = scenario.round_plan(rnd)
         for cl in plan.departed:
-            stream.remove(int(cl))
-            shard.remove(int(cl))
+            whole.remove(int(cl))
+            chunked.remove(int(cl))
         fresh = rs.dirichlet([0.4] * c, n).astype(np.float32)
-        want = stream.stale_clients(rnd, fresh, active=plan.active)
-        got = shard.stale_clients(rnd, fresh, active=plan.active)
+        want = whole.stale_clients(rnd, fresh, active=plan.active)
+        got = chunked.stale_clients(rnd, fresh, active=plan.active)
         np.testing.assert_array_equal(got, want)
         todo = [int(cl) for cl in got if rs.rand() > 0.25]
         if todo:
             summaries = rs.rand(len(todo), 8).astype(np.float32)
-            stream.update_batch(todo, rnd, summaries, fresh[todo])
-            shard.update_batch(todo, rnd, summaries, fresh[todo])
-        assert shard.refresh_count == stream.refresh_count
-        np.testing.assert_array_equal(shard.has_mask(), stream.has_mask())
-        np.testing.assert_array_equal(shard.last_refresh,
-                                      stream.last_refresh)
-        have = np.flatnonzero(shard.has_mask())
+            whole.update_batch(todo, rnd, summaries, fresh[todo])
+            chunked.update_batch(todo, rnd, summaries, fresh[todo])
+        assert chunked.refresh_count == whole.refresh_count
+        np.testing.assert_array_equal(chunked.has_mask(), whole.has_mask())
+        np.testing.assert_array_equal(chunked.last_refresh,
+                                      whole.last_refresh)
+        have = np.flatnonzero(chunked.has_mask())
         if have.size:
-            np.testing.assert_array_equal(shard.matrix_rows(have),
-                                          stream.matrix_rows(have))
+            np.testing.assert_array_equal(chunked.matrix_rows(have),
+                                          whole.matrix_rows(have))
 
 
 # ---------------------------------------------------------------------------
@@ -189,53 +192,34 @@ def churn_setup():
 
 
 @pytest.mark.slow
-def test_streaming_registry_e2e_equals_dict_under_churn(churn_setup):
+@pytest.mark.parametrize("layout", ["padded", "rows"])
+def test_chunked_registry_e2e_equals_all_rechecked_under_churn(
+        churn_setup, layout, monkeypatch):
+    """Identical refresh decisions + identical clustering input rows ⇒
+    the whole round trace (selection, clock, accuracy) must match between
+    a chunked device scan and a run whose every drift is re-decided by the
+    exact host math (an infinite decision margin)."""
     data, sc_config = churn_setup
-    h_dict = run_federated(data, _churn_cfg(registry="dict"),
-                           scenario=Scenario.from_config(sc_config))
-    h_stream = run_federated(data, _churn_cfg(registry="streaming"),
-                             scenario=Scenario.from_config(sc_config))
-    assert _trace(h_dict) == _trace(h_stream)
+    h_chunk = run_federated(data, _churn_cfg(**FLCONFIG[layout]),
+                            scenario=Scenario.from_config(sc_config))
+    monkeypatch.setattr(rounds, "SummaryRegistry", functools.partial(
+        SummaryRegistry, decision_margin=np.inf))
+    h_exact = run_federated(data, _churn_cfg(),
+                            scenario=Scenario.from_config(sc_config))
+    assert _trace(h_chunk) == _trace(h_exact)
 
 
 @pytest.mark.slow
-def test_batched_engine_e2e_equals_perclient_under_churn(churn_setup):
-    data, sc_config = churn_setup
-    h_batched = run_federated(data, _churn_cfg(summary_engine="batched"),
-                              scenario=Scenario.from_config(sc_config))
-    h_per = run_federated(data, _churn_cfg(summary_engine="perclient"),
-                          scenario=Scenario.from_config(sc_config))
-    assert _trace(h_batched) == _trace(h_per)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("registry", ["dict", "streaming", "sharded"])
-def test_async_server_e2e_equals_sync_under_churn(churn_setup, registry):
+@pytest.mark.parametrize("layout", list(FLCONFIG))
+def test_async_server_e2e_equals_sync_under_churn(churn_setup, layout):
     """The async selection server (zero ingest latency, sync refresh
     cadence — DESIGN.md §8) replays the sync trace bitwise under churn,
-    for every registry backend.  ``tests/test_server.py`` extends this
-    pin across 24 seeds and the clustering matrix."""
+    for every scan layout.  ``tests/test_server.py`` extends this pin
+    across 24 seeds and the clustering matrix."""
     data, sc_config = churn_setup
-    kw = {"shard_chunk_rows": 8} if registry == "sharded" else {}
-    h_sync = run_federated(data, _churn_cfg(registry=registry, **kw),
+    kw = FLCONFIG[layout]
+    h_sync = run_federated(data, _churn_cfg(**kw),
                            scenario=Scenario.from_config(sc_config))
-    h_async = run_federated(data,
-                            _churn_cfg(registry=registry, server="async",
-                                       **kw),
+    h_async = run_federated(data, _churn_cfg(server="async", **kw),
                             scenario=Scenario.from_config(sc_config))
     assert _trace(h_sync) == _trace(h_async)
-
-
-@pytest.mark.slow
-def test_sharded_registry_e2e_equals_streaming_under_churn(churn_setup):
-    """Identical refresh decisions + identical clustering input rows ⇒
-    the whole round trace (selection, clock, accuracy) must match when
-    only the registry implementation is swapped."""
-    data, sc_config = churn_setup
-    h_stream = run_federated(data, _churn_cfg(registry="streaming"),
-                             scenario=Scenario.from_config(sc_config))
-    h_shard = run_federated(data,
-                            _churn_cfg(registry="sharded",
-                                       shard_chunk_rows=8),
-                            scenario=Scenario.from_config(sc_config))
-    assert _trace(h_stream) == _trace(h_shard)

@@ -6,11 +6,12 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.core import RefreshPolicy, SelectionConfig, SummaryRegistry, \
-    cluster_quotas, select_devices, sym_kl
+from repro.core import RefreshPolicy, SelectionConfig, cluster_quotas, \
+    select_devices, sym_kl
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl import FLConfig, fedavg, run_federated
 from repro.fl.system import SystemModel, SystemSpec
+from repro.stream import SummaryRegistry
 from repro.utils.tree import tree_weighted_sum
 
 
@@ -86,7 +87,7 @@ def test_registry_refresh_logic():
     reg = SummaryRegistry(3, RefreshPolicy(max_age_rounds=5, kl_threshold=0.2))
     p = np.array([0.5, 0.5])
     assert reg.needs_refresh(0, 0, p)            # never computed
-    reg.update(0, 0, np.zeros(4), p)
+    reg.update_batch([0], 0, np.zeros((1, 4)), p[None])
     assert not reg.needs_refresh(0, 1, p)        # fresh
     assert reg.needs_refresh(0, 6, p)            # aged out
     drifted = np.array([0.95, 0.05])
@@ -140,8 +141,8 @@ def test_summary_refresh_reacts_to_drift():
 
 # ---------------------------------------------------------------------------
 # config validation: unknown backend strings must fail loudly (regression —
-# PR 4 covered clustering=, this pins registry= / summary_engine= / server=
-# too; repro.server config strings are pinned in tests/test_server.py)
+# this pins registry= / summary_engine= / clustering= / server=;
+# repro.server config strings are pinned in tests/test_server.py)
 
 
 @pytest.mark.parametrize("field,value,msg", [
@@ -153,6 +154,5 @@ def test_summary_refresh_reacts_to_drift():
 def test_unknown_backend_strings_rejected(field, value, msg):
     data = FederatedDataset(small_spec(num_clients=6, num_classes=3, side=8,
                                        avg_samples=12), seed=0)
-    cfg = FLConfig(rounds=1, **{field: value})
     with pytest.raises(ValueError, match=msg):
-        run_federated(data, cfg)
+        run_federated(data, FLConfig(rounds=1, **{field: value}))

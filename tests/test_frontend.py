@@ -26,12 +26,9 @@ from repro.server.snapshot import RegistrySnapshot
 from repro.sim import (
     FaultPlan, Scenario, ServerKilled, make_scenario, resume_trace,
 )
+from scan_layouts import FLCONFIG, MATRIX as _MATRIX
 
 SEEDS = range(24)          # >= 20 random seeds (acceptance floor)
-_MATRIX = [("dict", "kmeans"), ("streaming", "kmeans"),
-           ("sharded", "kmeans"), ("streaming", "online"),
-           ("sharded", "hierarchical"), ("streaming", "minibatch"),
-           ("dict", "online")]
 _PRESETS = ("mobile-churn", "straggler", "diurnal")
 
 
@@ -237,12 +234,12 @@ def fleet_data():
                                        avg_samples=24), seed=13)
 
 
-def _cfg(seed, registry="streaming", clustering="kmeans", rounds=4, **kw):
+def _cfg(seed, layout="padded", clustering="kmeans", rounds=4, **kw):
     base = dict(rounds=rounds, clients_per_round=4, local_steps=1,
-                summary="py", registry=registry, clustering=clustering,
-                num_clusters=3, refresh_max_age=3, refresh_kl=0.05,
-                recluster_every=2, shard_chunk_rows=8, hier_local_k=3,
-                eval_every=2, seed=seed, server="async")
+                summary="py", clustering=clustering, num_clusters=3,
+                refresh_max_age=3, refresh_kl=0.05, recluster_every=2,
+                hier_local_k=3, eval_every=2, seed=seed, server="async",
+                **FLCONFIG[layout])
     base.update(kw)
     return FLConfig(**base)
 
@@ -253,13 +250,13 @@ def test_noshed_frontend_pinned_to_plain_async(fleet_data, seed):
     """Unbounded queue + front end enabled ⇒ the front end is a pure
     observer: selection, refreshes, clock and accuracy replay the plain
     async run bitwise, whatever the backend."""
-    registry, clustering = _MATRIX[seed % len(_MATRIX)]
+    layout, clustering = _MATRIX[seed % len(_MATRIX)]
     preset = _PRESETS[seed % len(_PRESETS)]
     data = fleet_data
     sc = make_scenario(preset, data.spec.num_clients, seed=seed).to_config()
-    h_plain = run_federated(data, _cfg(seed, registry, clustering),
+    h_plain = run_federated(data, _cfg(seed, layout, clustering),
                             scenario=Scenario.from_config(sc))
-    h_front = run_federated(data, _cfg(seed, registry, clustering,
+    h_front = run_federated(data, _cfg(seed, layout, clustering,
                                        frontend="poisson"),
                             scenario=Scenario.from_config(sc))
     assert _trace(h_plain) == _trace(h_front)

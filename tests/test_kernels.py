@@ -94,8 +94,7 @@ def test_round_context_takes_reference_path_on_cpu(clustering):
     from repro.fl.rounds import RoundContext
     data = FederatedDataset(small_spec(num_clients=8, num_classes=4, side=8,
                                        avg_samples=16), seed=0)
-    ctx = RoundContext(data, FLConfig(registry="sharded",
-                                      clustering=clustering), None)
+    ctx = RoundContext(data, FLConfig(clustering=clustering), None)
     assert ctx.use_kernel is False
     assert ctx.engine.use_kernel is False
     assert ctx.maintainer.policy.use_kernel is False

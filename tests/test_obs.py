@@ -393,7 +393,7 @@ def _data(seed=13):
 
 def _cfg(**kw):
     base = dict(rounds=6, clients_per_round=4, local_steps=1, summary="py",
-                registry="streaming", clustering="online", num_clusters=3,
+                clustering="online", num_clusters=3,
                 refresh_max_age=3, refresh_kl=0.05, eval_every=3, seed=5)
     base.update(kw)
     return FLConfig(**base)
@@ -509,10 +509,10 @@ def test_blocking_counter_increments_exactly_at_the_bound():
     from repro.server import (
         ClusterRefresher, SnapshotStore, StalenessPolicy, capture,
     )
-    from repro.stream import StreamingSummaryRegistry
+    from repro.stream import SummaryRegistry
 
     n = 8
-    reg = StreamingSummaryRegistry(n, RefreshPolicy(4, 0.1))
+    reg = SummaryRegistry(n, RefreshPolicy(4, 0.1))
     reg.update_batch(np.arange(n), 0, np.ones((n, 3), np.float32),
                      np.full((n, 4), 0.25, np.float32))
     ctx = _RefresherCtx(reg)

@@ -13,6 +13,7 @@ from repro.core import dbscan, kmeans, minibatch_kmeans
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl import FLConfig
 from repro.fl.rounds import RoundContext
+from scan_layouts import FLCONFIG as LAYOUTS
 
 N, C, D, K, RND = 24, 4, 10, 3, 2
 
@@ -32,14 +33,14 @@ def _reference(cfg, rows):
 
 
 @pytest.mark.parametrize("fleet", ["whole", "churned"])
-@pytest.mark.parametrize("registry", ["dict", "streaming", "sharded"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
 @pytest.mark.parametrize("clustering", ["kmeans", "minibatch", "dbscan"])
-def test_recluster_input_is_matrix_rows(clustering, registry, fleet,
+def test_recluster_input_is_matrix_rows(clustering, layout, fleet,
                                         tmp_path):
     data = FederatedDataset(small_spec(num_clients=N, num_classes=C, side=8,
                                        avg_samples=16), seed=0)
-    cfg = FLConfig(registry=registry, clustering=clustering, num_clusters=K,
-                   summary="py", seed=3)
+    cfg = FLConfig(clustering=clustering, num_clusters=K, summary="py",
+                   seed=3, **LAYOUTS[layout])
     ctx = RoundContext(data, cfg, None)
     rng = np.random.default_rng(7)
     centers = rng.normal(size=(K, D)) * 4.0

@@ -4,7 +4,7 @@ The load-bearing claim is **pinned reconstruction**: ``explain`` answers
 "why was this client (not) selected" by replaying the recorded policy
 inputs, and the replay must reproduce the recorded ``selected`` list
 byte for byte — checked here live across the 24-seed differential
-matrix (registry × clustering × churn preset, sync / async / async+
+matrix (scan layout × clustering × churn preset, sync / async / async+
 front-end servers).  A reconstruction that merely *resembles* the
 decision would make ``why``'s attributions plausible-but-wrong; exact
 equality is what makes them trustworthy.
@@ -32,12 +32,9 @@ from repro.obs.recorder import (
 )
 from repro.server.events import Stage
 from repro.sim import FaultPlan, Scenario, ServerKilled, make_scenario
+from scan_layouts import FLCONFIG, MATRIX as _MATRIX
 
 SEEDS = range(24)
-_MATRIX = [("dict", "kmeans"), ("streaming", "kmeans"),
-           ("sharded", "kmeans"), ("streaming", "online"),
-           ("sharded", "hierarchical"), ("streaming", "minibatch"),
-           ("dict", "online")]
 _PRESETS = ("mobile-churn", "straggler", "diurnal")
 
 # the server-shape axis of the pin: plain sync loop, pipelined async
@@ -59,13 +56,12 @@ def recorder_data():
                                        side=8, avg_samples=24), seed=13)
 
 
-def _cfg(seed, server="sync", registry="streaming", clustering="online",
+def _cfg(seed, server="sync", layout="padded", clustering="online",
          rounds=4, **kw):
     base = dict(rounds=rounds, clients_per_round=4, local_steps=1,
-                summary="py", registry=registry, clustering=clustering,
-                num_clusters=3, refresh_max_age=3, refresh_kl=0.05,
-                recluster_every=2, shard_chunk_rows=8, hier_local_k=3,
-                eval_every=2, seed=seed)
+                summary="py", clustering=clustering, num_clusters=3,
+                refresh_max_age=3, refresh_kl=0.05, recluster_every=2,
+                hier_local_k=3, eval_every=2, seed=seed, **FLCONFIG[layout])
     if server == "sync":
         base["server"] = "sync"
     else:
@@ -182,13 +178,12 @@ def test_reconstruction_pins_selection_24seed(recorder_data, seed):
     """``reconstruct_selection`` must equal the recorded ``selected``
     list exactly, every round, for every matrix cell — live against the
     run that produced the record, not a canned fixture."""
-    registry, clustering = _MATRIX[seed % len(_MATRIX)]
+    layout, clustering = _MATRIX[seed % len(_MATRIX)]
     preset = _PRESETS[seed % len(_PRESETS)]
     server = _SERVERS[seed % len(_SERVERS)]
     data = recorder_data
     sc = make_scenario(preset, data.spec.num_clients, seed=seed).to_config()
-    cfg = _cfg(seed, server=server, registry=registry,
-               clustering=clustering)
+    cfg = _cfg(seed, server=server, layout=layout, clustering=clustering)
     with obs.observe(flight=True) as ob:
         h = run_federated(data, cfg, scenario=Scenario.from_config(sc))
     fl = Flight(ob.flight.records)
@@ -197,7 +192,7 @@ def test_reconstruction_pins_selection_24seed(recorder_data, seed):
         rec = fl.round_record(rnd)
         got = reconstruct_selection(rec)
         assert got == [int(c) for c in rec["selected"]], (
-            f"seed {seed} ({registry}/{clustering}/{preset}/{server}) "
+            f"seed {seed} ({layout}/{clustering}/{preset}/{server}) "
             f"round {rnd}: replay {got} != recorded {rec['selected']}")
         # the record agrees with the history trace it rode along with
         assert [int(c) for c in rec["selected"]] == \

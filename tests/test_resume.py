@@ -11,7 +11,7 @@ every ``(round, stage)`` boundary of the run, then completed.  Each
 segment exercises resume-from-the-previous-crash, so a single chain
 covers crash + resume at *every* boundary for the cost of a few
 uninterrupted runs (instead of one full run per boundary).  The slow
-sweep joins the 24-seed harness across the registry × clustering ×
+sweep joins the 24-seed harness across the scan layout × clustering ×
 churn-preset matrix from ``tests/test_server.py``, for both servers.
 """
 import os
@@ -26,12 +26,9 @@ from repro.server.events import Stage
 from repro.sim import (
     FaultPlan, Scenario, ServerKilled, make_scenario, resume_trace,
 )
+from scan_layouts import FLCONFIG, MATRIX as _MATRIX
 
 SEEDS = range(24)
-_MATRIX = [("dict", "kmeans"), ("streaming", "kmeans"),
-           ("sharded", "kmeans"), ("streaming", "online"),
-           ("sharded", "hierarchical"), ("streaming", "minibatch"),
-           ("dict", "online")]
 _PRESETS = ("mobile-churn", "straggler", "diurnal")
 
 # every boundary guaranteed to fire each round, per server (async INGEST
@@ -51,13 +48,13 @@ def resume_data():
                                        avg_samples=24), seed=13)
 
 
-def _cfg(seed, server, registry="dict", clustering="kmeans", rounds=3,
+def _cfg(seed, server, layout="whole", clustering="kmeans", rounds=3,
          **kw):
     base = dict(rounds=rounds, clients_per_round=4, local_steps=1,
-                summary="py", registry=registry, clustering=clustering,
-                num_clusters=3, refresh_max_age=3, refresh_kl=0.05,
-                recluster_every=2, shard_chunk_rows=8, hier_local_k=3,
-                eval_every=2, seed=seed, server=server)
+                summary="py", clustering=clustering, num_clusters=3,
+                refresh_max_age=3, refresh_kl=0.05, recluster_every=2,
+                hier_local_k=3, eval_every=2, seed=seed, server=server,
+                **FLCONFIG[layout])
     base.update(kw)
     return FLConfig(**base)
 
@@ -82,10 +79,10 @@ def _kill_chain(data, cfg, sc_config, boundaries, tmpdir):
     return h, killed
 
 
-def _chain_cell(data, seed, server, registry, clustering, preset, tmpdir,
+def _chain_cell(data, seed, server, layout, clustering, preset, tmpdir,
                 rounds=3):
     sc = make_scenario(preset, data.spec.num_clients, seed=seed).to_config()
-    cfg = _cfg(seed, server, registry, clustering, rounds=rounds)
+    cfg = _cfg(seed, server, layout, clustering, rounds=rounds)
     h0 = run_federated(data, cfg, scenario=Scenario.from_config(sc))
     boundaries = [(r, s) for r in range(rounds) for s in _STAGES[server]]
     h1, killed = _kill_chain(data, cfg, sc, boundaries, tmpdir)
@@ -102,7 +99,7 @@ def _chain_cell(data, seed, server, registry, clustering, preset, tmpdir,
 @pytest.mark.parametrize("server", ["sync", "async"])
 def test_kill_chain_every_boundary_quick(resume_data, server, tmp_path):
     h0, h1 = _chain_cell(resume_data, seed=1, server=server,
-                         registry="streaming", clustering="kmeans",
+                         layout="padded", clustering="kmeans",
                          preset="mobile-churn", tmpdir=str(tmp_path))
     if server == "async":
         # resumed counters match the uninterrupted run too — the
@@ -230,7 +227,7 @@ def test_checkpoint_cadence(resume_data, tmp_path):
 
 # ---------------------------------------------------------------------------
 # the full sweep: 24 seeds × both servers, rotating through the
-# registry × clustering × churn-preset matrix (same rotation as
+# scan layout × clustering × churn-preset matrix (same rotation as
 # tests/test_server.py, so every combo is hit across the seed range)
 
 
@@ -238,8 +235,8 @@ def test_checkpoint_cadence(resume_data, tmp_path):
 @pytest.mark.parametrize("server", ["sync", "async"])
 @pytest.mark.parametrize("seed", SEEDS)
 def test_kill_chain_matrix(resume_data, seed, server, tmp_path):
-    registry, clustering = _MATRIX[seed % len(_MATRIX)]
+    layout, clustering = _MATRIX[seed % len(_MATRIX)]
     preset = _PRESETS[seed % len(_PRESETS)]
-    _chain_cell(resume_data, seed=seed, server=server, registry=registry,
+    _chain_cell(resume_data, seed=seed, server=server, layout=layout,
                 clustering=clustering, preset=preset, tmpdir=str(tmp_path),
                 rounds=2)

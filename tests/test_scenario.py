@@ -1,6 +1,6 @@
 """Fleet scenario engine (repro/sim, DESIGN.md §6): config round-trip,
 deterministic replay, churn/availability/drift semantics, deadline
-straggler-timeout behavior, and the preset x registry x clustering support
+straggler-timeout behavior, and the preset x scan layout x clustering support
 matrix running end-to-end."""
 import numpy as np
 import pytest
@@ -12,6 +12,7 @@ from repro.fl.system import SystemSpec
 from repro.sim import (
     DATA_HINTS, PRESET_NAMES, Scenario, ScenarioConfig, make_scenario,
 )
+from scan_layouts import FLCONFIG
 
 PLAN_FIELDS = ("active", "available", "speeds", "drift", "joined",
                "departed", "fail_u", "upload_cost")
@@ -68,7 +69,7 @@ def test_run_federated_replay_identical():
                                        avg_samples=24), seed=6)
     config = make_scenario("mobile-churn", n, seed=8).to_config()
     cfg = FLConfig(rounds=4, clients_per_round=4, local_steps=2, summary="py",
-                   registry="streaming", clustering="kmeans", num_clusters=3,
+                   clustering="kmeans", num_clusters=3,
                    eval_every=2, seed=3)
     h1 = run_federated(data, cfg, scenario=Scenario.from_config(config))
     h2 = run_federated(data, cfg, scenario=Scenario.from_config(config))
@@ -155,7 +156,7 @@ def test_departed_clients_are_never_selected():
                                        avg_samples=24), seed=8)
     config = make_scenario("mobile-churn", n, seed=9).to_config()
     cfg = FLConfig(rounds=6, clients_per_round=5, local_steps=1, summary="py",
-                   registry="streaming", clustering="kmeans", num_clusters=3,
+                   clustering="kmeans", num_clusters=3,
                    eval_every=5, seed=6)
     h = run_federated(data, cfg, scenario=Scenario.from_config(config))
     # replay the scenario to recover the per-round membership
@@ -174,7 +175,7 @@ def test_joined_clients_get_summarized_and_participate():
     sc = make_scenario("mobile-churn", n, seed=11, deadline=None,
                        dropout_prob=0.0)
     cfg = FLConfig(rounds=8, clients_per_round=5, local_steps=1, summary="py",
-                   registry="streaming", clustering="kmeans", num_clusters=3,
+                   clustering="kmeans", num_clusters=3,
                    refresh_max_age=100, eval_every=7, seed=7)
     h = run_federated(data, cfg, scenario=sc)
     assert sum(h["n_joined"]) > 0
@@ -183,10 +184,10 @@ def test_joined_clients_get_summarized_and_participate():
 
 
 # ---------------------------------------------------------------------------
-# support matrix: presets x (registry x clustering), end-to-end
+# support matrix: presets x (scan layout x clustering), end-to-end
 
-# full support matrix: every registry x clustering cell (DESIGN.md §6)
-COMBOS = [(reg, clus) for reg in ("dict", "streaming")
+# full support matrix: every scan layout x clustering cell (DESIGN.md §6)
+COMBOS = [(layout, clus) for layout in ("whole", "padded")
           for clus in ("kmeans", "minibatch", "online")]
 
 
@@ -201,11 +202,11 @@ def matrix_data():
 def test_preset_runs_all_registry_clustering_combos(matrix_data, preset):
     data = matrix_data
     assert preset in DATA_HINTS
-    for registry, clustering in COMBOS:
+    for layout, clustering in COMBOS:
         sc = make_scenario(preset, data.spec.num_clients, seed=2)
         cfg = FLConfig(rounds=2, clients_per_round=3, local_steps=1,
-                       summary="py", registry=registry, clustering=clustering,
-                       num_clusters=2, hidden=16, eval_every=1, seed=2)
+                       summary="py", clustering=clustering, num_clusters=2,
+                       hidden=16, eval_every=1, seed=2, **FLCONFIG[layout])
         h = run_federated(data, cfg, scenario=sc)
         assert len(h["acc"]) == 2
         assert h["refreshes"][-1] > 0

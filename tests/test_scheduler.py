@@ -3,9 +3,8 @@ rounding (core/selection.py)."""
 import numpy as np
 import pytest
 
-from repro.core import (
-    RefreshPolicy, SummaryRegistry, batch_sym_kl, cluster_quotas, sym_kl,
-)
+from repro.core import RefreshPolicy, batch_sym_kl, cluster_quotas, sym_kl
+from repro.stream import SummaryRegistry
 
 
 # ---------------------------------------------------------------------------
@@ -57,7 +56,7 @@ def test_refresh_precedence_age_beats_small_kl():
     reg = SummaryRegistry(2, RefreshPolicy(max_age_rounds=3,
                                            kl_threshold=0.5))
     p = np.array([0.5, 0.5], np.float32)
-    reg.update(0, 0, np.zeros(4), p)
+    reg.update_batch([0], 0, np.zeros((1, 4)), p[None])
     assert not reg.needs_refresh(0, 2, p)      # fresh, identical P(y)
     assert reg.needs_refresh(0, 3, p)          # aged out despite KL == 0
     assert reg.needs_refresh(1, 0, p)          # never computed, always stale
@@ -67,7 +66,7 @@ def test_refresh_kl_fires_only_past_threshold():
     reg = SummaryRegistry(1, RefreshPolicy(max_age_rounds=100,
                                            kl_threshold=0.2))
     p = np.array([0.5, 0.5], np.float32)
-    reg.update(0, 0, np.zeros(4), p)
+    reg.update_batch([0], 0, np.zeros((1, 4)), p[None])
     near = np.array([0.55, 0.45], np.float32)
     far = np.array([0.97, 0.03], np.float32)
     assert sym_kl(p, near) <= 0.2 < sym_kl(p, far)
@@ -83,10 +82,10 @@ def test_vectorized_stale_scan_equals_per_client_loop(rs):
         fresh = rs.dirichlet([0.5] * c, n).astype(np.float32)
         want = [cl for cl in range(n)
                 if reg.needs_refresh(cl, rnd, fresh[cl])]
-        assert reg.stale_clients(rnd, fresh) == want
+        assert reg.stale_clients(rnd, fresh).tolist() == want
         for cl in want:
             if rs.rand() > 0.4:
-                reg.update(cl, rnd, rs.rand(6).astype(np.float32), fresh[cl])
+                reg.update_batch([cl], rnd, rs.rand(1, 6), fresh[cl][None])
 
 
 # ---------------------------------------------------------------------------

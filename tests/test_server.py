@@ -2,7 +2,7 @@
 engine, snapshot store, ingest queue and refresher, plus the 24-seed
 differential pin — ``server="async"`` with zero ingest latency and the
 sync refresh cadence produces traces bitwise-identical to
-``server="sync"`` across registry × clustering backends under churn.
+``server="sync"`` across scan layout × clustering backends under churn.
 """
 import numpy as np
 import pytest
@@ -15,7 +15,8 @@ from repro.server import (
     Stage, capture,
 )
 from repro.sim import Scenario, make_scenario
-from repro.stream import StreamingSummaryRegistry
+from repro.stream import SummaryRegistry
+from scan_layouts import FLCONFIG, MATRIX as _MATRIX
 
 SEEDS = range(24)          # >= 20 random seeds (acceptance floor)
 
@@ -69,7 +70,7 @@ def test_event_queue_unknown_kind_fails_loudly():
 
 
 def _registry(n=6, c=4):
-    reg = StreamingSummaryRegistry(n, RefreshPolicy(4, 0.1), num_classes=c)
+    reg = SummaryRegistry(n, RefreshPolicy(4, 0.1), num_classes=c)
     reg.update_batch([0, 2], 0, np.ones((2, 3), np.float32),
                      np.full((2, c), 0.25, np.float32))
     return reg
@@ -134,7 +135,7 @@ def test_staleness_policy_validates():
 
 # ---------------------------------------------------------------------------
 # the differential pin: async (degenerate) ≡ sync, 24 seeds, churn,
-# rotating through the registry × clustering support matrix
+# rotating through the scan layout × clustering support matrix
 
 
 def _trace(h):
@@ -144,11 +145,7 @@ def _trace(h):
 
 
 # each seed exercises one cell; 24 seeds cover every combination 3-4x,
-# including the sharded registry (multi-chunk scan) and churn scenarios
-_MATRIX = [("dict", "kmeans"), ("streaming", "kmeans"),
-           ("sharded", "kmeans"), ("streaming", "online"),
-           ("sharded", "hierarchical"), ("streaming", "minibatch"),
-           ("dict", "online")]
+# including the multi-chunk and one-row scans and churn scenarios
 _PRESETS = ("mobile-churn", "straggler", "diurnal")
 
 
@@ -163,15 +160,15 @@ def server_data():
 def test_async_degenerate_equals_sync_trace(server_data, seed):
     """Zero ingest latency + the sync refresh cadence ⇒ the event-driven
     server replays the sync trace bitwise (selection, refreshes, clock,
-    accuracy), whatever the registry/clustering backend."""
-    registry, clustering = _MATRIX[seed % len(_MATRIX)]
+    accuracy), whatever the scan layout/clustering backend."""
+    layout, clustering = _MATRIX[seed % len(_MATRIX)]
     preset = _PRESETS[seed % len(_PRESETS)]
     data = server_data
     sc = make_scenario(preset, data.spec.num_clients, seed=seed).to_config()
     base = dict(rounds=4, clients_per_round=4, local_steps=1, summary="py",
-                registry=registry, clustering=clustering, num_clusters=3,
-                refresh_max_age=3, refresh_kl=0.05, recluster_every=2,
-                shard_chunk_rows=8, hier_local_k=3, eval_every=2, seed=seed)
+                clustering=clustering, num_clusters=3, refresh_max_age=3,
+                refresh_kl=0.05, recluster_every=2, hier_local_k=3,
+                eval_every=2, seed=seed, **FLCONFIG[layout])
     h_sync = run_federated(data, FLConfig(**base, server="sync"),
                            scenario=Scenario.from_config(sc))
     h_async = run_federated(data, FLConfig(**base, server="async"),
@@ -190,15 +187,15 @@ def test_async_degenerate_equals_sync_trace(server_data, seed):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("registry", ["streaming", "sharded"])
-def test_staleness_mode_bounds_snapshot_age(server_data, registry):
+@pytest.mark.parametrize("layout", ["whole", "padded"])
+def test_staleness_mode_bounds_snapshot_age(server_data, layout):
     data = server_data
     sc = make_scenario("mobile-churn", data.spec.num_clients,
                        seed=5).to_config()
     cfg = FLConfig(rounds=8, clients_per_round=4, local_steps=1,
-                   summary="py", registry=registry, clustering="kmeans",
+                   summary="py", clustering="kmeans",
                    num_clusters=3, refresh_max_age=3, refresh_kl=0.05,
-                   shard_chunk_rows=8, eval_every=4, seed=5,
+                   eval_every=4, seed=5, **FLCONFIG[layout],
                    server="async", server_refresh="staleness",
                    ingest_delay_rounds=1, snapshot_max_age=2,
                    drift_mass_trigger=0.2)
@@ -228,7 +225,7 @@ def test_async_delay_defers_refreshes(server_data):
     sc = make_scenario("uniform-iid", data.spec.num_clients,
                        seed=2).to_config()
     base = dict(rounds=6, clients_per_round=4, local_steps=1, summary="py",
-                registry="streaming", clustering="kmeans", num_clusters=3,
+                clustering="kmeans", num_clusters=3,
                 refresh_max_age=2, refresh_kl=0.05, eval_every=3, seed=2,
                 server="async", server_refresh="staleness",
                 snapshot_max_age=3, drift_mass_trigger=0.1)
@@ -270,8 +267,7 @@ def test_sync_refresh_mode_survives_ingest_latency(server_data):
     for clustering in ("kmeans", "online"):
         h = run_federated(
             data, FLConfig(rounds=4, clients_per_round=4, local_steps=1,
-                           summary="py", registry="streaming",
-                           clustering=clustering, num_clusters=3,
+                           summary="py", clustering=clustering, num_clusters=3,
                            eval_every=2, seed=1, server="async",
                            ingest_delay_rounds=1),
             scenario=Scenario.from_config(sc))
@@ -289,7 +285,7 @@ def test_staleness_bound_holds_before_first_batch_lands(server_data):
     sc = make_scenario("uniform-iid", data.spec.num_clients,
                        seed=3).to_config()
     cfg = FLConfig(rounds=8, clients_per_round=4, local_steps=1,
-                   summary="py", registry="streaming", clustering="kmeans",
+                   summary="py", clustering="kmeans",
                    num_clusters=3, eval_every=4, seed=3, server="async",
                    server_refresh="staleness", ingest_delay_rounds=4,
                    snapshot_max_age=2, drift_mass_trigger=0.2)

@@ -1,6 +1,7 @@
 """Sharded fleet pipeline (DESIGN.md §7): decision exactness of the
-chunked device-mesh drift scan vs the streaming baseline, weighted-kmeans
-merge math, hierarchical clustering quality, and round-loop wiring.
+registry's chunked device-mesh drift scan vs the exact host math,
+weighted-kmeans merge math, hierarchical clustering quality, and
+round-loop wiring.
 
 Runs on whatever mesh the host exposes — CI re-runs this module under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` so the same
@@ -13,39 +14,43 @@ import pytest
 import jax.numpy as jnp
 
 import repro.obs as obs
-from repro.core import RefreshPolicy, kmeans, weighted_kmeans
+from repro.core import RefreshPolicy, batch_sym_kl, kmeans, weighted_kmeans
 from repro.data.synthetic import FederatedDataset, small_spec
 from repro.fl import FLConfig, run_federated
-from repro.shard import HierarchicalClusterMaintainer, ShardedSummaryRegistry
+from repro.shard import HierarchicalClusterMaintainer
 from repro.sim import drift_fleet, make_scenario, synthetic_fleet
-from repro.stream import StreamingSummaryRegistry
+from repro.stream import SummaryRegistry
+
+KL = 0.05
 
 
-def _seeded_pair(n, c, seed, **shard_kw):
-    policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=0.05)
+def _seeded(n, c, seed, **reg_kw):
+    policy = RefreshPolicy(max_age_rounds=10 ** 6, kl_threshold=KL)
     fleet = synthetic_fleet(n, c, 8, seed=seed)
-    stream = StreamingSummaryRegistry(n, policy)
-    shard = ShardedSummaryRegistry(n, policy, **shard_kw)
-    for reg in (stream, shard):
-        reg.update_batch(np.arange(n), 0, fleet.summaries, fleet.label_dists)
-    return fleet, stream, shard
+    shard = SummaryRegistry(n, policy, **reg_kw)
+    shard.update_batch(np.arange(n), 0, fleet.summaries, fleet.label_dists)
+    return fleet, shard
+
+
+def _exact_stale(fleet, fresh):
+    """The exact host decisions: every client holds a summary and none
+    ages out, so a client is stale iff its sym-KL passes the threshold."""
+    return np.flatnonzero(batch_sym_kl(fleet.label_dists, fresh) > KL)
 
 
 # ---------------------------------------------------------------------------
-# chunked scan: decisions equal streaming through every code path
+# chunked scan: decisions equal the exact math through every code path
 
 
 @pytest.mark.parametrize("chunk_rows", [7, 64, 10 ** 9])
-def test_chunked_scan_matches_streaming(chunk_rows):
+def test_chunked_scan_matches_exact(chunk_rows):
     """Multi-chunk + zero-padded tail, single padded chunk, and one whole-
-    fleet chunk all produce the streaming registry's exact stale set."""
-    fleet, stream, shard = _seeded_pair(301, 10, seed=0,
-                                        chunk_rows=chunk_rows)
+    fleet chunk all produce the exact host math's stale set."""
+    fleet, shard = _seeded(301, 10, seed=0, chunk_rows=chunk_rows)
     for rnd, frac in ((1, 0.05), (2, 0.5)):
         fresh, _ = drift_fleet(fleet.label_dists, frac, seed=rnd)
-        want = stream.stale_clients(rnd, fresh)
-        got = shard.stale_clients(rnd, fresh)
-        np.testing.assert_array_equal(want, got)
+        np.testing.assert_array_equal(shard.stale_clients(rnd, fresh),
+                                      _exact_stale(fleet, fresh))
     assert shard.chunk_rows % shard.n_shards == 0
     # two scans, each ceil(N / chunk) dispatches (tail chunk zero-padded)
     assert shard.scan_chunks == 2 * -(-301 // shard.chunk_rows)
@@ -54,14 +59,13 @@ def test_chunked_scan_matches_streaming(chunk_rows):
 def test_decision_margin_paths_agree():
     """Margin 0 (pure device drift) and a margin wider than every drift
     value (every row re-checked with the exact numpy math) bracket the
-    default band — all three must emit the streaming stale set."""
+    default band — all three must emit the exact stale set."""
     stale = []
     for margin in (0.0, 1e-4, 1e9):
-        fleet, stream, shard = _seeded_pair(200, 6, seed=3,
-                                            decision_margin=margin)
+        fleet, shard = _seeded(200, 6, seed=3, decision_margin=margin)
         fresh, _ = drift_fleet(fleet.label_dists, 0.1, seed=4)
-        np.testing.assert_array_equal(stream.stale_clients(1, fresh),
-                                      shard.stale_clients(1, fresh))
+        np.testing.assert_array_equal(shard.stale_clients(1, fresh),
+                                      _exact_stale(fleet, fresh))
         stale.append(shard.stale_clients(1, fresh))
         if margin == 1e9:
             assert shard.rechecked_rows >= 200   # exact path exercised
@@ -74,12 +78,12 @@ def test_padding_rows_never_go_stale():
     """With zero drift the tail-padding rows (all-zero dists on both
     sides) and the real rows all stay fresh — padding cannot leak into
     decisions."""
-    fleet, _, shard = _seeded_pair(45, 5, seed=7, chunk_rows=8)
+    fleet, shard = _seeded(45, 5, seed=7, chunk_rows=8)
     assert shard.stale_clients(1, fleet.label_dists).size == 0
 
 
 def test_registry_mesh_matches_host():
-    _, _, shard = _seeded_pair(20, 4, seed=1)
+    _, shard = _seeded(20, 4, seed=1)
     assert shard.n_shards == len(jax.devices())
 
 
@@ -161,9 +165,8 @@ def test_run_federated_sharded_hierarchical():
     data = FederatedDataset(small_spec(num_clients=24, num_classes=5,
                                        side=8, avg_samples=20), seed=5)
     cfg = FLConfig(rounds=3, clients_per_round=4, local_steps=2,
-                   summary="py", registry="sharded",
-                   clustering="hierarchical", num_clusters=3, n_shards=2,
-                   hier_local_k=4, eval_every=2, seed=1)
+                   summary="py", clustering="hierarchical", num_clusters=3,
+                   n_shards=2, hier_local_k=4, eval_every=2, seed=1)
     h = run_federated(data, cfg)
     assert len(h["round"]) == 3
     assert h["online_cluster"]["merges"] >= 1
@@ -179,9 +182,9 @@ def test_sharded_hierarchical_under_scenario_presets(preset):
     data = FederatedDataset(small_spec(num_clients=n, num_classes=5,
                                        side=8, avg_samples=20), seed=2)
     cfg = FLConfig(rounds=3, clients_per_round=4, local_steps=2,
-                   summary="py", registry="sharded",
-                   clustering="hierarchical", num_clusters=3, n_shards=2,
-                   hier_local_k=4, refresh_max_age=2, eval_every=2, seed=0)
+                   summary="py", clustering="hierarchical", num_clusters=3,
+                   n_shards=2, hier_local_k=4, refresh_max_age=2,
+                   eval_every=2, seed=0)
     h = run_federated(data, cfg, scenario=make_scenario(preset, n, seed=1))
     assert len(h["round"]) == 3
     assert h["online_cluster"]["merges"] >= 1
@@ -216,7 +219,7 @@ def test_profiled_rounds_count_every_copy(clustering, tmp_path):
     data = FederatedDataset(small_spec(num_clients=n, num_classes=c, side=8,
                                        avg_samples=20), seed=5)
     cfg = FLConfig(rounds=2, clients_per_round=4, local_steps=1,
-                   summary="py", registry="sharded", shard_chunk_rows=chunk,
+                   summary="py", shard_chunk_rows=chunk,
                    clustering=clustering, num_clusters=k, eval_every=2,
                    seed=1)
     n0 = len(obs.profiled().events)

@@ -1,6 +1,6 @@
 """Streaming subsystem (repro/stream, DESIGN.md §5): sketch algebra and
-error bounds, kernel-vs-oracle equivalence, streaming-registry decision
-equivalence against the baseline, and online-clustering quality on the
+error bounds, kernel-vs-oracle equivalence, the registry's decisions
+against the per-client predicate, and online-clustering quality on the
 drift scenario."""
 import itertools
 
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.core import RefreshPolicy, SummaryRegistry, kmeans
+from repro.core import RefreshPolicy, kmeans
 from repro.kernels import ops, ref
 from repro.kernels.sketch_update import cm_hash_params
 from repro.stream import (
@@ -18,7 +18,7 @@ from repro.stream import (
     OnlineClusterMaintainer,
     OnlinePolicy,
     SketchSpec,
-    StreamingSummaryRegistry,
+    SummaryRegistry,
     cm_estimate,
     cm_label_dist,
     cm_merge,
@@ -143,7 +143,7 @@ def test_cm_merge_commutative_and_associative(seed, n):
 @given(st.integers(0, 2 ** 31 - 1), st.integers(2, 50), st.integers(2, 4))
 def test_cm_update_concat_equals_merged_shards(seed, n, shards):
     """update on a concatenated batch == merge of per-shard updates — the
-    linearity the streaming registry leans on for shard/merge topologies."""
+    linearity the streaming subsystem leans on for shard/merge topologies."""
     rs = np.random.RandomState(seed)
     labels = rs.randint(0, 30, n).astype(np.int32)
     valid = rs.rand(n) > 0.15
@@ -175,42 +175,25 @@ def test_rp_update_concat_equals_merged_shards(seed, n):
 
 
 # ---------------------------------------------------------------------------
-# streaming registry == baseline registry, round for round
+# the registry's scan == the per-client predicate, round for round
 
 
-def test_streaming_registry_matches_baseline_decisions(rs):
+def test_registry_matches_per_client_predicate(rs):
     n, c = 40, 6
     policy = RefreshPolicy(max_age_rounds=4, kl_threshold=0.08)
-    base = SummaryRegistry(n, policy)
-    stream = StreamingSummaryRegistry(n, policy)
+    reg = SummaryRegistry(n, policy)
+    want_x = np.zeros((n, 12), np.float32)
     for rnd in range(15):
         fresh = rs.dirichlet([0.4] * c, n).astype(np.float32)
         want = [cl for cl in range(n)
-                if base.needs_refresh(cl, rnd, fresh[cl])]
-        assert base.stale_clients(rnd, fresh) == want        # vectorized dict
-        got = stream.stale_clients(rnd, fresh).tolist()      # streaming
-        assert got == want
+                if reg.needs_refresh(cl, rnd, fresh[cl])]
+        assert reg.stale_clients(rnd, fresh).tolist() == want
         # refresh only a random subset of the stale set (partial rounds)
         todo = [cl for cl in want if rs.rand() > 0.3]
         summaries = rs.rand(len(todo), 12).astype(np.float32)
-        stream.update_batch(todo, rnd, summaries, fresh[todo])
-        for i, cl in enumerate(todo):
-            base.update(cl, rnd, summaries[i], fresh[cl])
-        assert stream.refresh_count == base.refresh_count
-    if stream.has_summary.all():
-        np.testing.assert_array_equal(base.matrix(), stream.matrix())
-
-
-def test_streaming_registry_accepts_dict_signal(rs):
-    policy = RefreshPolicy(max_age_rounds=10, kl_threshold=0.05)
-    stream = StreamingSummaryRegistry(5, policy)
-    fresh = {cl: np.full(4, 0.25, np.float32) for cl in range(5)}
-    assert stream.stale_clients(0, fresh).tolist() == [0, 1, 2, 3, 4]
-    stream.update(2, 0, np.zeros(3, np.float32), fresh[2])
-    assert stream.stale_clients(1, fresh).tolist() == [0, 1, 3, 4]
-    assert not stream.needs_refresh(2, 1, fresh[2])
-    with pytest.raises(AssertionError):
-        stream.matrix()                                  # missing summaries
+        reg.update_batch(todo, rnd, summaries, fresh[todo])
+        want_x[todo] = summaries
+    np.testing.assert_array_equal(reg.dense(), want_x)
 
 
 def test_streaming_remove_evicts_stale_row(rs):
@@ -219,7 +202,7 @@ def test_streaming_remove_evicts_stale_row(rs):
     summary to clustering — it could still be clustered and selected."""
     n, c = 8, 5
     policy = RefreshPolicy(max_age_rounds=100, kl_threshold=0.05)
-    reg = StreamingSummaryRegistry(n, policy)
+    reg = SummaryRegistry(n, policy)
     fresh = rs.dirichlet([0.5] * c, n).astype(np.float32)
     summaries = rs.rand(n, 6).astype(np.float32) + 1.0    # no zero rows
     reg.update_batch(list(range(n)), 0, summaries, fresh)
@@ -234,7 +217,7 @@ def test_streaming_remove_evicts_stale_row(rs):
     assert reg.needs_refresh(3, 1, fresh[3])              # rejoin => stale
     assert np.all(reg.dense()[3] == 0)                    # row evicted
     with pytest.raises(AssertionError):
-        reg.matrix()                                      # fleet incomplete
+        reg.matrix_rows(np.arange(n))                     # fleet incomplete
 
     # while absent, the active mask keeps it out of the refresh set...
     active = np.ones(n, bool)
@@ -249,25 +232,24 @@ def test_streaming_remove_evicts_stale_row(rs):
     assert reg.stale_mask(1, fresh, active=active)[3]
 
 
-def test_dict_registry_remove_matches_streaming(rs):
-    """The baseline registry supports the same eviction path (differential
-    harness parity under churn)."""
+def test_registry_remove_matches_never_summarized(rs):
+    """An evicted client leaves the registry exactly as if it had never
+    been summarized: mask, refresh round, decisions and dense row."""
     n, c = 6, 4
     policy = RefreshPolicy(max_age_rounds=100, kl_threshold=0.05)
-    base = SummaryRegistry(n, policy)
-    stream = StreamingSummaryRegistry(n, policy)
+    evicted = SummaryRegistry(n, policy)
+    never = SummaryRegistry(n, policy)
     fresh = rs.dirichlet([0.5] * c, n).astype(np.float32)
-    for cl in range(n):
-        s = rs.rand(5).astype(np.float32)
-        base.update(cl, 0, s, fresh[cl])
-        stream.update(cl, 0, s, fresh[cl])
-    base.remove(2)
-    stream.remove(2)
-    np.testing.assert_array_equal(base.has_mask(), stream.has_mask())
-    np.testing.assert_array_equal(base.last_refresh, stream.last_refresh)
-    np.testing.assert_array_equal(base.stale_mask(1, fresh),
-                                  stream.stale_mask(1, fresh))
-    np.testing.assert_array_equal(base.dense(), stream.dense())
+    s = rs.rand(n, 5).astype(np.float32)
+    evicted.update_batch(np.arange(n), 0, s, fresh)
+    keep = np.delete(np.arange(n), 2)
+    never.update_batch(keep, 0, s[keep], fresh[keep])
+    evicted.remove(2)
+    np.testing.assert_array_equal(evicted.has_mask(), never.has_mask())
+    np.testing.assert_array_equal(evicted.last_refresh, never.last_refresh)
+    np.testing.assert_array_equal(evicted.stale_mask(1, fresh),
+                                  never.stale_mask(1, fresh))
+    np.testing.assert_array_equal(evicted.dense(), never.dense())
 
 
 # ---------------------------------------------------------------------------
@@ -355,7 +337,7 @@ def test_federated_streaming_online_path():
     data = FederatedDataset(small_spec(num_clients=14, num_classes=5, side=8,
                                        avg_samples=24), seed=5)
     cfg = FLConfig(rounds=5, clients_per_round=4, local_steps=2, summary="py",
-                   registry="streaming", clustering="online", num_clusters=3,
+                   clustering="online", num_clusters=3,
                    drift_start=2, drift_per_round=0.5, refresh_kl=0.05,
                    eval_every=4, seed=5)
     h = run_federated(data, cfg)

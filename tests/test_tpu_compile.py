@@ -25,8 +25,8 @@ from repro.core.kmeans import kmeans
 from repro.kernels import ops
 from repro.kernels.sketch_update import cm_hash_params
 from repro.models.cnn import CNNConfig, build_cnn, cnn_apply
-from repro.shard.registry import _drift_scan
 from repro.stream.cluster import _assign_fn
+from repro.stream.registry import _drift_scan
 
 C, H, K_CORESET = 62, 32, 64
 
